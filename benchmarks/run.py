@@ -46,6 +46,8 @@ import sys
 import time
 import traceback
 
+from repro.compat import enable_compile_cache
+
 MODULES = [
     "table2_accuracy",
     "fig2_weight_hist",
@@ -76,6 +78,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help=f"tiny-shape pass over {SMOKE_MODULES}")
     args = ap.parse_args()
+    enable_compile_cache()
     mods = (args.only.split(",") if args.only
             else SMOKE_MODULES if args.smoke else MODULES)
 

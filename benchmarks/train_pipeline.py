@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from benchmarks._common import emit_json, print_table
 from repro.checkpoint.io import BSR_MANIFEST, load_block_sparse
+from repro.compat import refuse_shared_accelerator
 from repro.core.dismec import DiSMECConfig
 from repro.data.xmc import make_xmc_dataset
 from repro.train.xmc import XMCTrainJob
@@ -196,6 +197,7 @@ def main(smoke: bool = False):
     # worker gets its own cores this approaches the worker count as the
     # batch count grows; with all workers packed on one small CPU the
     # number reports the contention honestly.
+    refuse_shared_accelerator(N_WORKERS, "the multiworker mode")
     with tempfile.TemporaryDirectory() as d:
         env = {**os.environ,
                "PYTHONPATH": "src" + (os.pathsep + os.environ["PYTHONPATH"]

@@ -11,8 +11,8 @@ Two claims of the margin-caching / double-buffering rework are measured:
                  (L, N)-score-shaped passes per iteration. The cached-mask
                  protocol (core/tron.py) threads the mask `obj_grad_fn`
                  already produced, leaving ONE. Counted from the compiled
-                 HLO of one CG iteration via `compat.cost_analysis`, cross-
-                 checked against `launch.hlo_cost`'s dot-walking parser:
+                 HLO of one CG iteration via `compiled.cost_analysis()`,
+                 cross-checked against `launch.hlo_cost`'s dot-walking parser:
                  passes = total matmul flops / one (L,N,D) contraction,
                  minus the unavoidable X^T (act * Xv) output contraction.
                  The legacy protocol is emulated through the act_aux payload
@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks._common import emit_json, print_table
-from repro.compat import cost_analysis
 from repro.core import losses
 from repro.core.dismec import DiSMECConfig
 from repro.core.pruning import prune
@@ -91,7 +90,7 @@ def score_passes(fn, *args) -> dict:
     contraction — the rest are score passes."""
     compiled = jax.jit(fn).lower(*args).compile()
     one_pass = 2.0 * L_CG * N_CG * D_CG
-    flops_ca = float(cost_analysis(compiled).get("flops", 0.0))
+    flops_ca = float(compiled.cost_analysis().get("flops", 0.0))
     flops_hlo = float(hlo_cost.summarize(compiled.as_text())["flops"])
     return {
         "flops_cost_analysis": flops_ca,

@@ -64,10 +64,12 @@ def main():
     import jax.numpy as jnp
 
     from repro.checkpoint.io import BSR_MANIFEST, load_block_sparse
+    from repro.compat import refuse_shared_accelerator
     from repro.core.prediction import evaluate
     from repro.data.xmc import make_xmc_dataset
     from repro.xmc_api import CheckpointHandle, fit
 
+    refuse_shared_accelerator(N_WORKERS, "this example")
     ctx = mp.get_context("spawn")                # fresh jax per worker
     with tempfile.TemporaryDirectory() as root:
         coop = os.path.join(root, "coop")

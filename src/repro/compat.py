@@ -1,59 +1,31 @@
-"""Version-compatibility shims for jax.
+"""Platform helpers shared by the kernels, meshes and launchers.
 
-The repo targets the `jax.shard_map` API (jax >= 0.6: top-level export,
-`check_vma=` keyword). On the pinned 0.4.x toolchain that function lives in
-`jax.experimental.shard_map` and the keyword is spelled `check_rep=`. Every
-call site imports `shard_map` from here instead of touching `jax.shard_map`
-directly, so the whole codebase moves between jax versions by editing this
-one file.
+The repo supports one jax version, the installed 0.9.0; nothing here
+branches on the version.
 """
 
 from __future__ import annotations
 
-import inspect
+import os
 
 import jax
+from jax.sharding import AxisType
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.6
-    _shard_map = jax.shard_map
-else:                                               # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """`jax.shard_map` with the `check_vma` keyword mapped to whatever the
-    installed jax calls it (`check_rep` before 0.6)."""
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """`jax.make_mesh` with every axis `Auto`.
 
-
-try:                                                # jax >= 0.5.x
-    from jax.sharding import AxisType
-except ImportError:
-    import enum
-
-    class AxisType(enum.Enum):                      # minimal stand-in
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-_MAKE_MESH_PARAMS = frozenset(inspect.signature(jax.make_mesh).parameters)
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, **kwargs):
-    """`jax.make_mesh` with `axis_types=` dropped on jax versions that
-    predate sharding-in-types (the old default is Auto everywhere, which is
-    exactly what the dropped argument requested)."""
-    if axis_types is not None and "axis_types" in _MAKE_MESH_PARAMS:
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    jax 0.9 makes every axis `Explicit` by default, and a gather on an
+    operand sharded over an explicit axis raises `ShardingTypeError`. The
+    solver and the sharded serving path leave sharding to the compiler, so
+    every mesh of the repo is built here."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def default_pallas_interpret() -> bool:
@@ -69,10 +41,34 @@ def resolve_interpret(interpret) -> bool:
     return default_pallas_interpret() if interpret is None else bool(interpret)
 
 
-def cost_analysis(compiled) -> dict:
-    """`compiled.cost_analysis()` as one flat dict on every jax version
-    (0.4.x returns a one-element list of per-program dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+def refuse_shared_accelerator(n_workers: int, what: str) -> None:
+    """Raise before starting `n_workers` worker processes that would all
+    need this process's accelerator.
+
+    A chip belongs to one process at a time: once this process has touched
+    it, a child that needs it fails or hangs. Worker subprocesses therefore
+    run only where the backend is the CPU."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} starts {n_workers} worker processes that would each "
+            f"need a {backend} chip, but this process already holds the "
+            f"{jax.local_device_count()} local chip(s) and a chip serves "
+            "one process at a time. Run it with JAX_PLATFORMS=cpu, or start "
+            "one worker per host.")
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compile cache and return its directory.
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set, jax already reads it and
+    nothing else is set. Otherwise the cache sits at one fixed path inside
+    the checkout, `<repo>/.jax_cache` (git-ignored): the path is part of
+    the cache key, so a directory that moved between runs would never hit.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

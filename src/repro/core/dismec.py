@@ -37,14 +37,13 @@ the (L, D) x (D, N) score matmul just to rebuild the active set.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import losses
 from repro.core.tron import TronResult, tron_solve
 from repro.core.pruning import prune
@@ -246,12 +245,21 @@ def balance_permutation(Y: Array, n_shards: int) -> np.ndarray:
     return perm
 
 
+class BatchSolve(NamedTuple):
+    """One label batch's solve: the Delta-pruned weights and, per label,
+    the Newton and CG iterations TRON spent on it."""
+    W: Array            # (rows, D), pruned
+    n_newton: Array     # (rows,) int32
+    n_cg: Array         # (rows,) int32
+
+
 def make_batch_solver(X: Array, cfg: DiSMECConfig, mesh: Optional[Mesh] = None,
                       *, label_axis: str = "model", data_axis: str = "data",
                       shard_data: bool = False, warm: bool = False):
     """Layer 2 of Algorithm 1 as a reusable jitted solver: (S (rows, N),
-    W0 (rows, D) or None) -> Delta-pruned W (rows, D), rows a multiple of
-    the label-shard count when a mesh is given. The one code path behind
+    W0 (rows, D) or None) -> `BatchSolve` (Delta-pruned W (rows, D) plus
+    per-label iteration counts), rows a multiple of the label-shard count
+    when a mesh is given. The one code path behind
     `train`, `train_sharded` and the streaming scheduler (train/xmc.py) —
     the scheduler keeps every label batch the same padded shape so all
     batches share one executable.
@@ -273,11 +281,17 @@ def make_batch_solver(X: Array, cfg: DiSMECConfig, mesh: Optional[Mesh] = None,
                        — via one extra obj/grad evaluation at W=0 per batch.
                        Without the anchor a warm W0's small gradient would
                        TIGHTEN the tolerance and un-converge every label.
+
+    With a mesh, X is placed on it once, here, so no batch re-sends it to
+    the devices. A host (numpy) X goes to each device as its own slice; a
+    device-resident X would first be cut into every device's slice on its
+    own device, which needs that device to hold X several times over.
     """
-    X = jnp.asarray(X, jnp.float32)
+    xp = jnp if isinstance(X, jax.Array) else np
+    X = xp.asarray(X, xp.float32)
     D = X.shape[1]
 
-    def run_tron(obj_grad, hvp, W0: Array) -> Array:
+    def run_tron(obj_grad, hvp, W0: Array) -> BatchSolve:
         ref = None
         if warm:
             _, g_zero, _ = obj_grad(jnp.zeros_like(W0))
@@ -285,18 +299,20 @@ def make_batch_solver(X: Array, cfg: DiSMECConfig, mesh: Optional[Mesh] = None,
         res = tron_solve(obj_grad, hvp, W0, eps=cfg.eps,
                          max_newton=cfg.max_newton, max_cg=cfg.max_cg,
                          gnorm_ref=ref)
-        return prune(res.W, cfg.delta)                  # step 7 on-device
+        # Step 7 (prune) runs on device.
+        return BatchSolve(prune(res.W, cfg.delta), res.n_newton, res.n_cg)
 
-    def solve_local(X_in: Array, S_in: Array, W0: Array) -> Array:
+    def solve_local(X_in: Array, S_in: Array, W0: Array) -> BatchSolve:
         obj_grad, hvp = _make_fns(X_in, S_in, cfg)
         return run_tron(obj_grad, hvp, W0)
 
     if mesh is None:
+        X = jnp.asarray(X)
         # X stays a traced argument (not a captured constant): XLA would
         # otherwise try to constant-fold whole X contractions at compile.
         jitted = jax.jit(solve_local)
 
-        def solve_single(S: Array, W0: Optional[Array] = None) -> Array:
+        def solve_single(S: Array, W0: Optional[Array] = None) -> BatchSolve:
             if W0 is None:
                 W0 = jnp.zeros((S.shape[0], D), jnp.float32)
             return jitted(X, S, W0)
@@ -311,12 +327,12 @@ def make_batch_solver(X: Array, cfg: DiSMECConfig, mesh: Optional[Mesh] = None,
         N = X.shape[0]
         n_pad = (-N) % n_data                           # instance padding
         if n_pad:
-            X = jnp.concatenate(
-                [X, jnp.zeros((n_pad, D), X.dtype)], axis=0)
+            X = xp.concatenate([X, xp.zeros((n_pad, D), X.dtype)], axis=0)
         s_spec = P(label_axis, data_axis)
         x_spec = P(data_axis, None)
+    X = jax.device_put(X, NamedSharding(mesh, x_spec))
 
-    def solve_shard(X_sh: Array, S_sh: Array, W0_sh: Array) -> Array:
+    def solve_shard(X_sh: Array, S_sh: Array, W0_sh: Array) -> BatchSolve:
         if shard_data:
             # Margin-caching protocol over the data axis: the act payload is
             # the LOCAL (rows, N/n_data) mask of this shard's instance slice
@@ -342,11 +358,14 @@ def make_batch_solver(X: Array, cfg: DiSMECConfig, mesh: Optional[Mesh] = None,
             return run_tron(obj_grad, hvp, W0_sh)
         return solve_local(X_sh, S_sh, W0_sh)
 
-    shmapped = shard_map(solve_shard, mesh=mesh,
-                         in_specs=(x_spec, s_spec, P(label_axis, None)),
-                         out_specs=P(label_axis, None), check_vma=False)
+    shmapped = jax.shard_map(
+        solve_shard, mesh=mesh,
+        in_specs=(x_spec, s_spec, P(label_axis, None)),
+        out_specs=BatchSolve(P(label_axis, None), P(label_axis),
+                             P(label_axis)),
+        check_vma=False)
 
-    def solve(X_in: Array, S: Array, W0: Array) -> Array:
+    def solve(X_in: Array, S: Array, W0: Array) -> BatchSolve:
         if n_pad:
             S = jnp.concatenate(
                 [S, -jnp.ones((S.shape[0], n_pad), S.dtype)], axis=1)
@@ -354,7 +373,7 @@ def make_batch_solver(X: Array, cfg: DiSMECConfig, mesh: Optional[Mesh] = None,
 
     jitted = jax.jit(solve)
 
-    def solve_meshed(S: Array, W0: Optional[Array] = None) -> Array:
+    def solve_meshed(S: Array, W0: Optional[Array] = None) -> BatchSolve:
         if W0 is None:
             W0 = jnp.zeros((S.shape[0], D), jnp.float32)
         return jitted(X, S, W0)

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 Array = jax.Array
 
@@ -69,9 +68,9 @@ def predict_topk_sharded(X: Array, W: Array, k: int, mesh: Mesh,
         i_top = jnp.take_along_axis(i_all, pos, axis=1)
         return s_top, i_top
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(), P(label_axis, None)),
-                   out_specs=(P(), P()), check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(), P(label_axis, None)),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(X, W)
 
 

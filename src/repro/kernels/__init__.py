@@ -2,7 +2,7 @@
 
 Each kernel directory contains:
   kernel.py — pl.pallas_call body + BlockSpec tiling (TPU target)
-  ops.py    — jit'd public wrapper with shape checks / fallbacks
+  ops.py    — jit'd public wrapper: padding and shape/VMEM-bound checks
   ref.py    — pure-jnp oracle the tests assert against
 
 Kernels (DESIGN.md §3):
@@ -11,12 +11,13 @@ Kernels (DESIGN.md §3):
               solver protocol, core/tron.py)
   hvp         fused generalized-Hessian vector product consuming the
               cached mask (CG inner loop)
-  bsr_predict block-sparse W x predict — skips Delta-pruned zero blocks
-  topk        blocked two-stage top-k for distributed prediction
+  bsr_predict block-sparse W x predict — skips Delta-pruned zero blocks;
+              its top-k is `jax.lax.top_k` over the (n, L) scores
 
-All kernels are validated on CPU with interpret=True; on TPU the same
-pallas_call lowers to Mosaic. The training kernels (hinge/hvp) take
-`interpret=None` and auto-select per backend (compiled Mosaic on TPU,
-interpreter elsewhere — compat.default_pallas_interpret). VMEM budgets
-are documented per kernel.
+Every kernel takes `interpret=None` and auto-selects per backend
+(compiled Mosaic on TPU, the interpreter elsewhere —
+compat.default_pallas_interpret). The CPU tests check each kernel against
+its oracle in interpret mode; tests/kernels/test_tpu_compile.py compiles
+each for a described v5e chip, which enforces the (8, 128) block tiling
+and the VMEM budgets documented per kernel.
 """

@@ -6,7 +6,7 @@ Tiling
 ------
 grid = (B * KV, nq): one step per (batch x kv-head group, query block).
 The query block (G, qc, hd) lives in VMEM via BlockSpec; K/V stay UNBLOCKED
-(memory_space ANY -> HBM on TPU) and the kernel pl.loads exactly the
+(memory_space ANY -> HBM on TPU) and the kernel reads exactly the
 [band_start, band_start + span) rows it attends to — the DMA the XLA-level
 implementation relies on the compiler to find, made explicit.
 
@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.compat import resolve_interpret
+
 DEFAULT_QC = 256
 NEG_INF = float(-3.0e38)
 
@@ -44,14 +46,8 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, *, window: int, span: int,
 
     q_end = (qi + 1) * qc
     start = jnp.clip(q_end - span, 0, Tk - span)
-    # The leading batch index must be a traced scalar, not a Python int:
-    # jax 0.4.x's interpret-mode discharge rule assumes every non-Slice
-    # index has a .shape.
-    zero = jnp.int32(0)
-    k = pl.load(k_ref, (zero, pl.ds(start, span), slice(None))
-                ).astype(jnp.float32)                  # (span, hd)
-    v = pl.load(v_ref, (zero, pl.ds(start, span), slice(None))
-                ).astype(jnp.float32)
+    k = k_ref[0, pl.ds(start, span), :].astype(jnp.float32)   # (span, hd)
+    v = v_ref[0, pl.ds(start, span), :].astype(jnp.float32)
 
     qf = q.reshape(G * qc, hd)
     s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
@@ -72,7 +68,7 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, *, window: int, span: int,
 
 def banded_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             *, window: int, qc: int = DEFAULT_QC,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool | None = None) -> jax.Array:
     """q (BKV, G, Tq, hd), k/v (BKV, Tk, hd) -> (BKV, G, Tq, hd).
 
     Requires Tq % qc == 0 and span <= Tk (ops.py pads/validates).
@@ -96,5 +92,5 @@ def banded_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, G, qc, hd), lambda b, i: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BKV, G, Tq, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

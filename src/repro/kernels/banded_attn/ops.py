@@ -26,7 +26,7 @@ def _vmem_bytes(G: int, qc: int, hd: int, span: int) -> int:
 @partial(jax.jit, static_argnames=("window", "qc", "interpret"))
 def banded_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      *, window: int, qc: int = DEFAULT_QC,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """Sliding-window attention, (B, Tq, H, hd) x (B, Tk, KV, hd) GQA layout
     (same convention as models/layers.py) -> (B, Tq, H * hd).
 

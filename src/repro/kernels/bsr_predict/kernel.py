@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import resolve_interpret
+
 DEFAULT_BLOCK = (128, 128)
 
 
@@ -46,7 +48,7 @@ def _bsr_kernel(rows_ref, cols_ref, x_ref, blk_ref, o_ref):
 
 def bsr_predict_pallas(x: jax.Array, blocks: jax.Array, block_rows: jax.Array,
                        block_cols: jax.Array, n_row_blocks: int,
-                       *, interpret: bool = True) -> jax.Array:
+                       *, interpret: bool | None = None) -> jax.Array:
     """x (n, Dp), blocks (nb, bl, bd) row-major packed -> scores (n, Lp).
 
     Row-blocks with no surviving blocks are never visited; ops.py masks them.
@@ -63,7 +65,7 @@ def bsr_predict_pallas(x: jax.Array, blocks: jax.Array, block_rows: jax.Array,
     return pl.pallas_call(
         _bsr_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, n_row_blocks * bl), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_rows, block_cols, x, blocks)
 
 
@@ -90,7 +92,7 @@ def _bsr_int8_kernel(rows_ref, cols_ref, scales_ref, x_ref, blk_ref, o_ref):
 def bsr_predict_int8_pallas(x: jax.Array, blocks: jax.Array,
                             scales: jax.Array, block_rows: jax.Array,
                             block_cols: jax.Array, n_row_blocks: int,
-                            *, interpret: bool = True) -> jax.Array:
+                            *, interpret: bool | None = None) -> jax.Array:
     """x (n, Dp), blocks (nb, bl, bd) int8 row-major packed, scales (nb,)
     fp32 -> scores (n, Lp) fp32. HBM traffic for the model payload is
     nb*bl*bd bytes + 4*nb scale bytes — ~0.25x the fp32 kernel's.
@@ -115,7 +117,7 @@ def bsr_predict_int8_pallas(x: jax.Array, blocks: jax.Array,
     return pl.pallas_call(
         _bsr_int8_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, n_row_blocks * bl), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_rows, block_cols, scales, x, blocks)
 
 
@@ -147,7 +149,7 @@ def _bsr_gather_kernel(sel_ref, rptr_ref, cols_ref, x_ref, blk_ref, o_ref):
 def bsr_predict_gather_pallas(x: jax.Array, blocks: jax.Array,
                               block_cols: jax.Array, row_ptr: jax.Array,
                               sel: jax.Array, max_blocks_per_row: int,
-                              *, interpret: bool = True) -> jax.Array:
+                              *, interpret: bool | None = None) -> jax.Array:
     """Gathered-block BSR predict: score only the row blocks listed in `sel`.
 
     x (n, Dp), blocks (nb, bl, bd) row-major packed, row_ptr (R + 1,),
@@ -185,7 +187,7 @@ def bsr_predict_gather_pallas(x: jax.Array, blocks: jax.Array,
     return pl.pallas_call(
         _bsr_gather_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, B * bl), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(sel, row_ptr, block_cols, x, blocks)
 
 
@@ -215,8 +217,9 @@ def _bsr_gather_int8_kernel(sel_ref, rptr_ref, cols_ref, scales_ref,
 def bsr_predict_gather_int8_pallas(x: jax.Array, blocks: jax.Array,
                                    scales: jax.Array, block_cols: jax.Array,
                                    row_ptr: jax.Array, sel: jax.Array,
-                                   max_blocks_per_row: int,
-                                   *, interpret: bool = True) -> jax.Array:
+                                   max_blocks_per_row: int, *,
+                                   interpret: bool | None = None,
+                                   ) -> jax.Array:
     """Gathered-block int8 predict: the shortlist fine stage over int8
     tiles. Same contract as `bsr_predict_gather_pallas` with (blocks int8,
     scales fp32) replacing the fp32 blocks; padding grid steps fetch a
@@ -249,7 +252,7 @@ def bsr_predict_gather_int8_pallas(x: jax.Array, blocks: jax.Array,
     return pl.pallas_call(
         _bsr_gather_int8_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, B * bl), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(sel, row_ptr, block_cols, scales, x, blocks)
 
 
@@ -276,15 +279,16 @@ def _bsr_gather_pq_kernel(sel_ref, rptr_ref, cols_ref, x_ref, blk_ref, o_ref):
 
     @pl.when(rptr_ref[r] + j < rptr_ref[r + 1])
     def _acc():
-        o_ref[...] += jax.lax.dot_general(
-            x_ref[...].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
+        o_ref[0] += jax.lax.dot_general(
+            x_ref[0].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def bsr_predict_gather_pq_pallas(x: jax.Array, blocks: jax.Array,
                                  block_cols: jax.Array, row_ptr: jax.Array,
                                  sel: jax.Array, max_blocks_per_row: int,
-                                 *, interpret: bool = True) -> jax.Array:
+                                 *, interpret: bool | None = None,
+                                 ) -> jax.Array:
     """Per-query gathered-block BSR predict: row q scores only ITS row
     blocks `sel[q]`.
 
@@ -297,7 +301,10 @@ def bsr_predict_gather_pq_pallas(x: jax.Array, blocks: jax.Array,
     The grid is (n, B, max_blocks_per_row) with j innermost, so each
     (1, bl) output tile stays resident across its row block's packed
     blocks. Both index maps clamp the packed pointer to nb - 1 so padding
-    steps fetch a valid tile; the body gates their accumulation off.
+    steps fetch a valid tile; the body gates their accumulation off. x and
+    the scores travel as (n, 1, width) arrays: a block's last two dims are
+    then (1, full) — a (1, bd) block of an (n, Dp) array breaks the TPU's
+    (8, 128) tiling rule whenever n > 1.
 
     Numerics note: the per-query dot is (1, bd) @ (bd, bl) — NOT bitwise
     identical to one row of the shared kernel's (n, bd) @ (bd, bl) dot on
@@ -319,21 +326,23 @@ def bsr_predict_gather_pq_pallas(x: jax.Array, blocks: jax.Array,
         num_scalar_prefetch=3,
         grid=(n, B, max_blocks_per_row),
         in_specs=[
-            pl.BlockSpec((1, bd),
+            pl.BlockSpec((1, 1, bd),
                          lambda q, i, j, sel_a, rptr_a, cols_a:
-                         (q, cols_a[_ptr(q, i, j, sel_a, rptr_a, cols_a)])),
+                         (q, 0, cols_a[_ptr(q, i, j, sel_a, rptr_a,
+                                            cols_a)])),
             pl.BlockSpec((1, bl, bd),
                          lambda q, i, j, sel_a, rptr_a, cols_a:
                          (_ptr(q, i, j, sel_a, rptr_a, cols_a), 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, bl), lambda q, i, j, sel_a, rptr_a, cols_a: (q, i)),
+            (1, 1, bl), lambda q, i, j, sel_a, rptr_a, cols_a: (q, 0, i)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _bsr_gather_pq_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, B * bl), jnp.float32),
-        interpret=interpret,
-    )(sel, row_ptr, block_cols, x, blocks)
+        out_shape=jax.ShapeDtypeStruct((n, 1, B * bl), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(sel, row_ptr, block_cols, x[:, None, :], blocks)
+    return out[:, 0, :]
 
 
 def _bsr_gather_pq_int8_kernel(sel_ref, rptr_ref, cols_ref, scales_ref,
@@ -353,8 +362,8 @@ def _bsr_gather_pq_int8_kernel(sel_ref, rptr_ref, cols_ref, scales_ref,
     @pl.when(rptr_ref[r] + j < rptr_ref[r + 1])
     def _acc():
         ptr = rptr_ref[r] + j            # in-bounds inside the gate
-        o_ref[...] += scales_ref[ptr] * jax.lax.dot_general(
-            x_ref[...].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
+        o_ref[0] += scales_ref[ptr] * jax.lax.dot_general(
+            x_ref[0].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
@@ -362,8 +371,9 @@ def bsr_predict_gather_pq_int8_pallas(x: jax.Array, blocks: jax.Array,
                                       scales: jax.Array,
                                       block_cols: jax.Array,
                                       row_ptr: jax.Array, sel: jax.Array,
-                                      max_blocks_per_row: int,
-                                      *, interpret: bool = True) -> jax.Array:
+                                      max_blocks_per_row: int, *,
+                                      interpret: bool | None = None,
+                                      ) -> jax.Array:
     """Per-query gathered-block int8 predict: same contract as
     `bsr_predict_gather_pq_pallas` with (blocks int8, scales fp32)
     replacing the fp32 blocks."""
@@ -378,21 +388,22 @@ def bsr_predict_gather_pq_int8_pallas(x: jax.Array, blocks: jax.Array,
         num_scalar_prefetch=4,
         grid=(n, B, max_blocks_per_row),
         in_specs=[
-            pl.BlockSpec((1, bd),
+            pl.BlockSpec((1, 1, bd),
                          lambda q, i, j, sel_a, rptr_a, cols_a, scales_a:
-                         (q, cols_a[_ptr(q, i, j, sel_a, rptr_a, cols_a,
-                                         scales_a)])),
+                         (q, 0, cols_a[_ptr(q, i, j, sel_a, rptr_a, cols_a,
+                                            scales_a)])),
             pl.BlockSpec((1, bl, bd),
                          lambda q, i, j, sel_a, rptr_a, cols_a, scales_a:
                          (_ptr(q, i, j, sel_a, rptr_a, cols_a, scales_a),
                           0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, bl),
-            lambda q, i, j, sel_a, rptr_a, cols_a, scales_a: (q, i)),
+            (1, 1, bl),
+            lambda q, i, j, sel_a, rptr_a, cols_a, scales_a: (q, 0, i)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _bsr_gather_pq_int8_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, B * bl), jnp.float32),
-        interpret=interpret,
-    )(sel, row_ptr, block_cols, scales, x, blocks)
+        out_shape=jax.ShapeDtypeStruct((n, 1, B * bl), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(sel, row_ptr, block_cols, scales, x[:, None, :], blocks)
+    return out[:, 0, :]

@@ -1,9 +1,9 @@
 """Public wrapper: BSR prediction over a pruned DiSMEC model.
 
 `bsr_predict` yields the dense (n, Lp) score matrix; `bsr_predict_topk`
-fuses it with the blocked Pallas top-k (kernels/topk) into the serving
-entry point used by `repro.serve.xmc.BsrBackend` — scores never leave the
-padded block coordinate system before being reduced to k candidates.
+reduces it with `jax.lax.top_k` into the serving entry point used by
+`repro.serve.xmc.BsrBackend` — scores never leave the padded block
+coordinate system before being reduced to k candidates.
 
 `bsr_predict_gather` / `bsr_predict_gather_topk` are the shortlist-gated
 variants (serve/shortlist.py): given a per-batch list of selected row
@@ -25,7 +25,10 @@ from repro.kernels.bsr_predict.kernel import (bsr_predict_gather_int8_pallas,
                                               bsr_predict_gather_pq_pallas,
                                               bsr_predict_int8_pallas,
                                               bsr_predict_pallas)
-from repro.kernels.topk.kernel import NEG_INF
+
+# Score of a masked (padding) label: loses to every real score, finite so
+# no -inf reaches a comparison.
+NEG_INF = float(-3.0e38)
 
 
 def _pad_features(x: jax.Array, model) -> jax.Array:
@@ -59,7 +62,7 @@ def _mask_empty_row_blocks(out: jax.Array, model) -> jax.Array:
 
 
 def bsr_predict(x: jax.Array, model: BlockSparseModel,
-                *, interpret: bool = True) -> jax.Array:
+                *, interpret: bool | None = None) -> jax.Array:
     """Scores (n, L) for a batch against a block-sparse model.
 
     Pads x's feature dim to the padded model shape (raising when the
@@ -75,7 +78,7 @@ def bsr_predict(x: jax.Array, model: BlockSparseModel,
 
 
 def bsr_predict_int8(x: jax.Array, model: Int8BlockSparseModel,
-                     *, interpret: bool = True) -> jax.Array:
+                     *, interpret: bool | None = None) -> jax.Array:
     """Scores (n, L) against the int8 per-block-scaled artifact — same
     pad/mask conventions as `bsr_predict`, ~0.25x the model HBM traffic.
     Scores match the fp32 path within the per-block quantization bound
@@ -91,40 +94,37 @@ def bsr_predict_int8(x: jax.Array, model: Int8BlockSparseModel,
 
 def bsr_predict_topk(x: jax.Array, model: BlockSparseModel, k: int,
                      *, n_labels: int | None = None,
-                     interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                     interpret: bool | None = None,
+                     ) -> tuple[jax.Array, jax.Array]:
     """Fused predict -> top-k: (vals, idx) each (n, k), idx in true label ids.
 
-    Padding label rows (id >= n_labels) are masked to -inf between the two
-    kernels so a block-padded model never serves phantom labels. Fully
+    Padding label rows (id >= n_labels) are masked to NEG_INF before the
+    top-k so a block-padded model never serves phantom labels. Fully
     pruned real labels keep their exact-zero score, matching the dense path.
     """
-    from repro.kernels.topk import ops as topk_ops   # deferred: no cycle
-
     scores = bsr_predict(x, model, interpret=interpret)
     Lp = scores.shape[1]
     if n_labels is not None and n_labels < Lp:
         ids = jnp.arange(Lp)
         scores = jnp.where(ids[None, :] < n_labels, scores, NEG_INF)
-    return topk_ops.topk(scores, k, interpret=interpret)
+    return jax.lax.top_k(scores, k)
 
 
 def bsr_predict_int8_topk(x: jax.Array, model: Int8BlockSparseModel, k: int,
                           *, n_labels: int | None = None,
-                          interpret: bool = True,
+                          interpret: bool | None = None,
                           ) -> tuple[jax.Array, jax.Array]:
     """Fused int8 predict -> top-k: (vals, idx) each (n, k), idx in true
     label ids — the `"int8"` backend's serving entry point. Padding labels
-    are masked to -inf between the kernels and fully pruned real labels
+    are masked to NEG_INF before the top-k and fully pruned real labels
     keep their exact-zero score (an all-zero block quantizes to scale 0),
     matching the fp32 conventions."""
-    from repro.kernels.topk import ops as topk_ops   # deferred: no cycle
-
     scores = bsr_predict_int8(x, model, interpret=interpret)
     Lp = scores.shape[1]
     if n_labels is not None and n_labels < Lp:
         ids = jnp.arange(Lp)
         scores = jnp.where(ids[None, :] < n_labels, scores, NEG_INF)
-    return topk_ops.topk(scores, k, interpret=interpret)
+    return jax.lax.top_k(scores, k)
 
 
 def max_blocks_per_row(model: BlockSparseModel) -> int:
@@ -137,7 +137,7 @@ def max_blocks_per_row(model: BlockSparseModel) -> int:
 def bsr_predict_gather(x: jax.Array, model: BlockSparseModel,
                        sel: jax.Array, *,
                        max_per_row: int | None = None,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """Scores for ONLY the row blocks listed in `sel` (B,) int32.
 
     Returns (n, B * bl): columns [i*bl, (i+1)*bl) are row block sel[i]'s
@@ -157,7 +157,7 @@ def bsr_predict_gather(x: jax.Array, model: BlockSparseModel,
 def bsr_predict_gather_int8(x: jax.Array, model: Int8BlockSparseModel,
                             sel: jax.Array, *,
                             max_per_row: int | None = None,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool | None = None) -> jax.Array:
     """Int8 scores for ONLY the row blocks listed in `sel` (B,) int32 —
     the shortlist fine stage over the quantized artifact. Same contract
     as `bsr_predict_gather` (exact-zero empty blocks included: their
@@ -174,19 +174,17 @@ def bsr_predict_gather_topk(x: jax.Array, model: BlockSparseModel,
                             sel: jax.Array, k: int, *,
                             n_labels: int | None = None,
                             max_per_row: int | None = None,
-                            interpret: bool = True,
+                            interpret: bool | None = None,
                             ) -> tuple[jax.Array, jax.Array]:
     """Fused gathered predict -> top-k over the shortlisted labels only.
 
     (vals, idx) each (n, k); idx in TRUE label ids (candidates translated
     back through `sel`). Padding labels (global id >= n_labels) are masked
-    to -inf between the kernels. With `sel` sorted ascending and covering
+    to NEG_INF before the top-k. With `sel` sorted ascending and covering
     every row block this reproduces `bsr_predict_topk` exactly, tie order
     included — the B-covers-all equivalence the shortlist backend tests
     gate on.
     """
-    from repro.kernels.topk import ops as topk_ops   # deferred: no cycle
-
     bl = model.block_shape[0]
     sel = jnp.asarray(sel, jnp.int32)
     scores = bsr_predict_gather(x, model, sel, max_per_row=max_per_row,
@@ -196,7 +194,7 @@ def bsr_predict_gather_topk(x: jax.Array, model: BlockSparseModel,
     label_ids = (sel[:, None] * bl + jnp.arange(bl)[None, :]).reshape(-1)
     if n_labels is not None:
         scores = jnp.where(label_ids[None, :] < n_labels, scores, NEG_INF)
-    vals, idx = topk_ops.topk(scores, k, interpret=interpret)
+    vals, idx = jax.lax.top_k(scores, k)
     return vals, jnp.take(label_ids, idx)
 
 
@@ -204,15 +202,13 @@ def bsr_predict_gather_int8_topk(x: jax.Array, model: Int8BlockSparseModel,
                                  sel: jax.Array, k: int, *,
                                  n_labels: int | None = None,
                                  max_per_row: int | None = None,
-                                 interpret: bool = True,
+                                 interpret: bool | None = None,
                                  ) -> tuple[jax.Array, jax.Array]:
     """Fused gathered int8 predict -> top-k: the shortlist backend's fine
     stage over the quantized artifact. Same contract as
     `bsr_predict_gather_topk` (idx in true label ids, padding masked, sorted
     full-coverage `sel` reproduces `bsr_predict_int8_topk` bit-for-bit —
     the scale multiplies the same per-block fp32 dot in the same order)."""
-    from repro.kernels.topk import ops as topk_ops   # deferred: no cycle
-
     bl = model.block_shape[0]
     sel = jnp.asarray(sel, jnp.int32)
     scores = bsr_predict_gather_int8(x, model, sel, max_per_row=max_per_row,
@@ -220,14 +216,14 @@ def bsr_predict_gather_int8_topk(x: jax.Array, model: Int8BlockSparseModel,
     label_ids = (sel[:, None] * bl + jnp.arange(bl)[None, :]).reshape(-1)
     if n_labels is not None:
         scores = jnp.where(label_ids[None, :] < n_labels, scores, NEG_INF)
-    vals, idx = topk_ops.topk(scores, k, interpret=interpret)
+    vals, idx = jax.lax.top_k(scores, k)
     return vals, jnp.take(label_ids, idx)
 
 
 def bsr_predict_gather_pq(x: jax.Array, model: BlockSparseModel,
                           sel: jax.Array, *,
                           max_per_row: int | None = None,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """Per-query gathered scores: row q scores ONLY its blocks `sel[q]`.
 
     sel (n, B) int32 (each row sorted, no duplicates) -> (n, B * bl): row
@@ -246,7 +242,7 @@ def bsr_predict_gather_pq(x: jax.Array, model: BlockSparseModel,
 def bsr_predict_gather_pq_int8(x: jax.Array, model: Int8BlockSparseModel,
                                sel: jax.Array, *,
                                max_per_row: int | None = None,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool | None = None) -> jax.Array:
     """Per-query gathered int8 scores — `bsr_predict_gather_pq` over the
     quantized artifact."""
     x = _pad_features(x, model)
@@ -258,19 +254,17 @@ def bsr_predict_gather_pq_int8(x: jax.Array, model: Int8BlockSparseModel,
 
 
 def _pq_translate_topk(scores: jax.Array, sel: jax.Array, bl: int, k: int,
-                       n_labels: int | None, interpret: bool,
+                       n_labels: int | None,
                        ) -> tuple[jax.Array, jax.Array]:
     """Shared tail of the per-query topk wrappers: mask block padding per
     row and translate merged top-k back to true label ids via each row's
     own candidate list."""
-    from repro.kernels.topk import ops as topk_ops   # deferred: no cycle
-
     # (n, B*bl): row q's candidate column c is label sel[q, c//bl]*bl + c%bl.
     label_ids = (sel[:, :, None] * bl
                  + jnp.arange(bl)[None, None, :]).reshape(sel.shape[0], -1)
     if n_labels is not None:
         scores = jnp.where(label_ids < n_labels, scores, NEG_INF)
-    vals, idx = topk_ops.topk(scores, k, interpret=interpret)
+    vals, idx = jax.lax.top_k(scores, k)
     return vals, jnp.take_along_axis(label_ids, idx, axis=1)
 
 
@@ -278,17 +272,17 @@ def bsr_predict_gather_pq_topk(x: jax.Array, model: BlockSparseModel,
                                sel: jax.Array, k: int, *,
                                n_labels: int | None = None,
                                max_per_row: int | None = None,
-                               interpret: bool = True,
+                               interpret: bool | None = None,
                                ) -> tuple[jax.Array, jax.Array]:
     """Fused per-query gathered predict -> top-k over each row's own
     shortlist. (vals, idx) each (n, k); idx in TRUE label ids (row q's
     candidates translated through sel[q]). Padding labels are masked to
-    -inf between the kernels, same as every other topk wrapper here."""
+    NEG_INF before the top-k, same as every other topk wrapper here."""
     bl = model.block_shape[0]
     sel = jnp.asarray(sel, jnp.int32)
     scores = bsr_predict_gather_pq(x, model, sel, max_per_row=max_per_row,
                                    interpret=interpret)
-    return _pq_translate_topk(scores, sel, bl, k, n_labels, interpret)
+    return _pq_translate_topk(scores, sel, bl, k, n_labels)
 
 
 def bsr_predict_gather_pq_int8_topk(x: jax.Array,
@@ -296,7 +290,7 @@ def bsr_predict_gather_pq_int8_topk(x: jax.Array,
                                     sel: jax.Array, k: int, *,
                                     n_labels: int | None = None,
                                     max_per_row: int | None = None,
-                                    interpret: bool = True,
+                                    interpret: bool | None = None,
                                     ) -> tuple[jax.Array, jax.Array]:
     """Fused per-query gathered int8 predict -> top-k: same contract as
     `bsr_predict_gather_pq_topk` over the quantized artifact."""
@@ -305,7 +299,7 @@ def bsr_predict_gather_pq_int8_topk(x: jax.Array,
     scores = bsr_predict_gather_pq_int8(x, model, sel,
                                         max_per_row=max_per_row,
                                         interpret=interpret)
-    return _pq_translate_topk(scores, sel, bl, k, n_labels, interpret)
+    return _pq_translate_topk(scores, sel, bl, k, n_labels)
 
 
 def gather_flops(model: BlockSparseModel, n: int, sel: np.ndarray) -> int:

@@ -1,4 +1,4 @@
-"""Public wrapper for the fused hinge kernel: padding, bounds, fallback."""
+"""Public wrapper for the fused hinge kernel: padding and the D bound."""
 
 from __future__ import annotations
 
@@ -7,8 +7,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.hinge import ref
-from repro.kernels.hinge.kernel import (MAX_FUSED_D, hinge_obj_grad_pallas)
+from repro.kernels.hinge.kernel import hinge_obj_grad_pallas, max_fused_d
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value: float = 0.0):
@@ -19,6 +18,16 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value: float = 0.0):
     pad = [(0, 0)] * x.ndim
     pad[axis] = (0, p)
     return jnp.pad(x, pad, constant_values=value)
+
+
+def check_fused_d(D: int, bl: int, bn: int) -> None:
+    """Raise when full-width (bl|bn, D) blocks cannot fit VMEM."""
+    bound = max_fused_d(bl, bn)
+    if D > bound:
+        raise ValueError(
+            f"feature dim D={D} exceeds the fused-kernel bound "
+            f"max_fused_d(bl={bl}, bn={bn})={bound}: full-width blocks "
+            "would not fit VMEM; use SolverSpec(ops='jnp') at this width")
 
 
 @partial(jax.jit, static_argnames=("C", "bl", "bn", "interpret"))
@@ -38,8 +47,7 @@ def objective_grad_act(W: jax.Array, X: jax.Array, S: jax.Array, C: float,
     """
     L, D = W.shape
     N = X.shape[0]
-    if D > MAX_FUSED_D:
-        return ref.objective_grad_act(W, X, S, C)
+    check_fused_d(D, bl, bn)
 
     Wp = _pad_to(W, 0, bl)
     Xp = _pad_to(X, 0, bn)

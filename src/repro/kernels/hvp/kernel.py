@@ -4,8 +4,9 @@
 
 This runs once per CG iteration per Newton step — by far the most-executed
 compute in DiSMEC training. Same (L/bl, N/bn) accumulation tiling as the
-hinge kernel (see kernels/hinge/kernel.py for the VMEM budget): the (bl, bn)
-masked intermediate act * (X v) lives only in VMEM.
+hinge kernel, and the same blocks, so the same VMEM bound
+(`kernels/hinge/kernel.py`, `max_fused_d`): the (bl, bn) masked
+intermediate act * (X v) lives only in VMEM.
 
 `act` is the active-set payload the fused hinge kernel emitted at the
 current Newton iterate (the margin-caching protocol, core/tron.py) — this
@@ -25,10 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.compat import resolve_interpret
-
-DEFAULT_BL = 128
-DEFAULT_BN = 128
-MAX_FUSED_D = 8192
+from repro.kernels.hinge.kernel import DEFAULT_BL, DEFAULT_BN
 
 
 def _hvp_kernel(v_ref, x_ref, a_ref, o_ref, *, C: float):

@@ -1,5 +1,5 @@
-"""Public wrapper for the Hessian-vector-product kernel: padding, bounds,
-fallback — the same arbitrary-shape contract as the hinge wrapper, so the
+"""Public wrapper for the Hessian-vector-product kernel: padding and the D
+bound — the same arbitrary-shape contract as the hinge wrapper, so the
 Pallas training path works on any (L, N, D) instead of silently requiring
 tile-aligned inputs (the raw `hvp_pallas` rejects those loudly)."""
 
@@ -8,20 +8,9 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
-from repro.kernels.hvp import ref
-from repro.kernels.hvp.kernel import MAX_FUSED_D, hvp_pallas
-
-
-def _pad_to(x: jax.Array, axis: int, mult: int, value: float = 0.0):
-    n = x.shape[axis]
-    p = (-n) % mult
-    if p == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, p)
-    return jnp.pad(x, pad, constant_values=value)
+from repro.kernels.hinge.ops import _pad_to, check_fused_d
+from repro.kernels.hvp.kernel import hvp_pallas
 
 
 @partial(jax.jit, static_argnames=("C", "bl", "bn", "interpret"))
@@ -40,8 +29,7 @@ def hessian_vp(V: jax.Array, X: jax.Array, act: jax.Array, C: float,
             f"act must be the (L, N) = {(L, N)} active mask matching V/X; "
             f"got {act.shape} — pass the mask emitted by "
             "kernels.hinge.ops.objective_grad_act at the same iterate")
-    if D > MAX_FUSED_D:
-        return ref.hessian_vp(V, X, act, C)
+    check_fused_d(D, bl, bn)
     Vp = _pad_to(V, 0, bl)
     Xp = _pad_to(X, 0, bn)
     Ap = _pad_to(_pad_to(act, 0, bl), 1, bn)

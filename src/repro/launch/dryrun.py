@@ -21,7 +21,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import SHAPES
 from repro.configs.registry import ARCH_IDS, SKIPS, get_config
 from repro.launch import specs as S
@@ -127,7 +126,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     coll = collective_bytes(hlo_text)
     from repro.launch import hlo_cost

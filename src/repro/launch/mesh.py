@@ -2,25 +2,24 @@
 
 A FUNCTION, not a module-level constant: importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first jax init).
-Mesh construction goes through repro.compat so the same code runs on jax
-versions with and without `AxisType` / `axis_types=`.
+Meshes are built by `repro.compat.make_mesh`, which makes every axis
+`Auto`.
 """
 
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+from repro.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over locally available devices (tests / examples)."""
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_shape_dict(mesh) -> dict:

@@ -38,6 +38,7 @@ from contextlib import contextmanager
 import jax
 import numpy as np
 
+from repro.compat import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config
 
 #: --model value: NAME=CKPT_DIR[,key=value...]; these keys override the
@@ -316,6 +317,7 @@ def main() -> None:
     ap.add_argument("--labels", type=int, default=256)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.xmc:
         if args.server:

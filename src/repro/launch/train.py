@@ -38,7 +38,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.compat import enable_compile_cache
 from repro.data.lm import make_lm_batch_iterator
+from repro.launch.mesh import make_host_mesh
 from repro.models.model import build_model
 from repro.models import sharding as shd
 from repro.train.trainer import train_loop
@@ -161,6 +163,7 @@ def main() -> None:
                          "and the batch is re-dealt (crash recovery)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.xmc:
         train_xmc(args)
@@ -178,7 +181,7 @@ def main() -> None:
     batch_axes = ()
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_host_mesh(d, m)
         batch_axes = ("data",)
 
     def batches():

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 
 Array = jax.Array
@@ -197,7 +196,7 @@ def moe_ffn(cfg: ArchConfig, p: dict, x: Array, *,
     if shared is not None:
         shared_specs = {"w1": P(fsdp, model_axis), "w3": P(fsdp, model_axis),
                         "w2": P(model_axis, fsdp), "gate": P(fsdp, None)}
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(fsdp, None), P(None, fsdp, model_axis),
                   P(None, fsdp, model_axis), P(None, model_axis, fsdp),

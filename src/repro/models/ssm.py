@@ -31,7 +31,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 
 Array = jax.Array
@@ -324,8 +323,8 @@ def slstm(cfg: ArchConfig, p: dict, x: Array, return_state: bool = False,
     def body(xl, pl_):
         return _slstm_impl(cfg, pl_, xl, return_state)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(bspec, wspec),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(bspec, wspec),
+                       out_specs=out_specs, check_vma=False)
     return fn(x, p)
 
 

@@ -215,7 +215,7 @@ def build_learned_shortlist(model, X, Y, *, C: float = 1.0,
     signs = (2.0 * Yb.T - 1.0).astype(np.float32)          # (R, N)
     cfg = DiSMECConfig(C=C, delta=0.0, eps=eps, max_newton=max_newton)
     solver = make_batch_solver(jnp.asarray(Xn), cfg)
-    W = np.asarray(solver(jnp.asarray(signs), None))       # (R, D)
+    W = np.asarray(solver(jnp.asarray(signs), None).W)     # (R, D)
     Wp = np.zeros((R, Dp), np.float32)
     Wp[:, :W.shape[1]] = W
     return ShortlistArtifact(centroids=Wp, block_rows=bl,

@@ -20,8 +20,8 @@ One engine, four built-in interchangeable backends behind the
 
   dense     — jitted X @ W.T + lax.top_k on the densified model. Baseline
               and reference semantics.
-  bsr       — the block-sparse Pallas predict kernel fused with the blocked
-              Pallas top-k (kernels/bsr_predict.ops.bsr_predict_topk); the
+  bsr       — the block-sparse Pallas predict kernel, then lax.top_k
+              (kernels/bsr_predict.ops.bsr_predict_topk); the
               model stays in packed BSR form end-to-end, compute scales
               with block density.
   sharded   — label-sharded local-topk + all-gather merge
@@ -84,7 +84,9 @@ from typing import Iterable, Protocol, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compat import resolve_interpret
 from repro.core.prediction import predict_topk_sharded
 from repro.core.pruning import (BlockSparseModel, Int8BlockSparseModel,
                                 quantize_block_sparse, to_block_sparse)
@@ -350,17 +352,18 @@ class DenseBackend:
 
 
 class BsrBackend:
-    """Packed block-sparse model through the Pallas predict+topk kernels."""
+    """Packed block-sparse model through the Pallas predict kernel + top-k."""
 
     name = "bsr"
 
     def __init__(self, model: BlockSparseModel, k: int,
-                 *, n_labels: int | None = None, interpret: bool = True):
+                 *, n_labels: int | None = None,
+                 interpret: bool | None = None):
         self.k = k
         self.n_labels = int(n_labels if n_labels is not None
                             else model.n_labels)
         self.model = model
-        self._interpret = bool(interpret)
+        self._interpret = resolve_interpret(interpret)
 
     def warmup_key(self):
         m = self.model
@@ -387,14 +390,14 @@ class Int8Backend:
     name = "int8"
 
     def __init__(self, model, k: int, *, n_labels: int | None = None,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
         if isinstance(model, BlockSparseModel):
             model = quantize_block_sparse(model)
         self.k = k
         self.n_labels = int(n_labels if n_labels is not None
                             else model.n_labels)
         self.model = model
-        self._interpret = bool(interpret)
+        self._interpret = resolve_interpret(interpret)
 
     def warmup_key(self):
         # Leads with a distinct kind tag AND the int8 dtype: an int8 backend
@@ -444,7 +447,7 @@ class ShortlistBackend:
 
     def __init__(self, model: BlockSparseModel, artifact: ShortlistArtifact,
                  k: int, *, n_labels: int | None = None,
-                 blocks: int | None = None, interpret: bool = True,
+                 blocks: int | None = None, interpret: bool | None = None,
                  int8: bool = False, int8_model=None,
                  per_query: bool = False):
         from repro.kernels.bsr_predict import ops as bsr_ops
@@ -470,7 +473,7 @@ class ShortlistBackend:
                           jnp.asarray(artifact.tree_leaf_scores),
                           int(artifact.tree_depth))
         self._max_per_row = bsr_ops.max_blocks_per_row(model)
-        self._interpret = bool(interpret)
+        self._interpret = resolve_interpret(interpret)
         # int8 composition: the coarse stage is unchanged (fp32 — tiny next
         # to the fine stage), the gathered fine stage scores quantized
         # tiles. Pass `int8_model` to reuse a persisted artifact; otherwise
@@ -610,17 +613,19 @@ class ShardedBackend:
         if Lp != L:                                 # shard-divisibility pad
             W = jnp.concatenate(
                 [W, jnp.zeros((Lp - L, W.shape[1]), W.dtype)], axis=0)
-        self._W = jnp.asarray(W)
-        self._fn = jax.jit(
-            lambda x: predict_topk_sharded(x, self._W, k, mesh,
-                                           label_axis=label_axis,
-                                           n_labels=self.n_labels))
+        # Placed label-sharded once, so no request re-sends W to the mesh.
+        # W is an argument of the jitted function, not a closure constant:
+        # a captured array would be baked into every bucket's executable.
+        self._W = jax.device_put(W, NamedSharding(mesh, P(label_axis, None)))
+        self._fn = jax.jit(functools.partial(
+            predict_topk_sharded, k=k, mesh=mesh, label_axis=label_axis,
+            n_labels=self.n_labels))
 
     def warmup_key(self):
-        return None        # mesh-bound closure: never share warm-up state
+        return None        # mesh-bound executable: never share warm-up state
 
     def topk(self, x: Array) -> tuple[Array, Array]:
-        return self._fn(x)
+        return self._fn(x, self._W)
 
 
 # ---------------------------------------------------------------------------
@@ -721,7 +726,7 @@ def _make_shortlist_backend(bsr: BlockSparseModel, k: int, *, n_labels: int,
 
 def make_backend(kind: str, bsr: BlockSparseModel, k: int, *,
                  n_labels: int | None = None, mesh=None,
-                 label_axis: str = "model", interpret: bool = True,
+                 label_axis: str = "model", interpret: bool | None = None,
                  shortlist: ShortlistArtifact | None = None,
                  shortlist_blocks: int | None = None,
                  int8: bool = False,
@@ -849,7 +854,7 @@ class XMCEngine:
 
     @classmethod
     def from_checkpoint(cls, directory: str, *, backend: str = "bsr",
-                        k: int = 5, mesh=None, interpret: bool = True,
+                        k: int = 5, mesh=None, interpret: bool | None = None,
                         buckets: Sequence[int] = DEFAULT_BUCKETS,
                         warmup: bool = True,
                         shortlist_blocks: int | None = None,
@@ -888,7 +893,7 @@ class XMCEngine:
     @classmethod
     def from_dismec(cls, model, *, backend: str = "dense", k: int = 5,
                     mesh=None, block_shape: tuple[int, int] = (128, 128),
-                    interpret: bool = True,
+                    interpret: bool | None = None,
                     buckets: Sequence[int] = DEFAULT_BUCKETS,
                     warmup: bool = False,
                     shortlist_blocks: int | None = None,

@@ -136,6 +136,15 @@ class XMCTrainResult:
     skipped: list[int]             # batch ids resumed from the manifest
     complete: bool                 # all batches present (checkpoint servable)
     manifest: Optional[dict]       # final manifest when streamed + complete
+    # One dict per batch THIS run solved, in solve order: label count,
+    # TRON's Newton and CG iterations (max and mean over the batch's
+    # labels), `wall_s` — host-clock seconds from when the batch could
+    # start (its submission to the solver, or the previous batch's
+    # arrival) to when its weights reached the host, i.e. the pipeline's
+    # period, set by the slower of the device solve and the previous
+    # batch's pack + write; the first batch's includes the solver compile
+    # — and `write_s`, this batch's BSR pack + shard write on the host.
+    batch_stats: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -313,8 +322,7 @@ class XMCTrainJob:
                 meta=meta_full, label_order=label_order)
             done = writer.done_batches
 
-        X_dev = jnp.asarray(X, jnp.float32)
-        solver = make_batch_solver(X_dev, self.cfg, self.mesh,
+        solver = make_batch_solver(X, self.cfg, self.mesh,
                                    label_axis=self.label_axis,
                                    data_axis=self.data_axis,
                                    shard_data=self.shard_data,
@@ -323,6 +331,8 @@ class XMCTrainJob:
         host_blocks: dict[int, np.ndarray] = {}
         solved: list[int] = []
         skipped: list[int] = []
+        batch_stats: list[dict] = []
+        last_ready = [0.0]
 
         # Multi-host layer 1: with a worker identity (explicit, or implied
         # by workers > 1) batches are claimed from the shared manifest's
@@ -359,14 +369,25 @@ class XMCTrainJob:
             if rows < lb_solve:                           # shape-constant pad
                 signs = np.concatenate(
                     [signs, -np.ones((lb_solve - rows, N), np.float32)])
-            return b, start, rows, perm, solver(jnp.asarray(signs), W0)[:rows]
+            t_submit = time.time()
+            sol = solver(jnp.asarray(signs), W0)
+            return (b, start, rows, perm, t_submit, sol.W[:rows],
+                    sol.n_newton[:rows], sol.n_cg[:rows])
 
         def drain(item) -> None:
             """Device->host transfer + BSR pack + shard write of one solved
             batch (paper's steps 8-10) — the leg that overlaps batch b+1's
             device compute when `overlap=True`."""
-            b, start, rows, perm, W_dev = item
+            b, start, rows, perm, t_submit, W_dev, newton, cg = item
             W_b = np.asarray(W_dev)
+            t_ready = time.time()
+            newton, cg = np.asarray(newton), np.asarray(cg)
+            stats = {"batch": b, "labels": rows,
+                     "newton_max": int(newton.max()),
+                     "newton_mean": float(newton.mean()),
+                     "cg_max": int(cg.max()), "cg_mean": float(cg.mean()),
+                     "wall_s": t_ready - max(t_submit, last_ready[0])}
+            last_ready[0] = t_ready
             if perm is not None:
                 W_b = W_b[np.argsort(perm)]               # undo shard dealing
             if writer is not None:
@@ -379,6 +400,8 @@ class XMCTrainJob:
                 # The manifest commit inside write_batch also releases
                 # this batch's lease.
                 writer.write_batch(b, part, row_start=start, n_rows=rows)
+            stats["write_s"] = time.time() - t_ready
+            batch_stats.append(stats)
             with held_lock:
                 held.discard(b)
             if materialize:
@@ -529,7 +552,7 @@ class XMCTrainJob:
         return XMCTrainResult(model=model, out_dir=out_dir,
                               n_batches=len(batches), solved=solved,
                               skipped=skipped, complete=complete,
-                              manifest=manifest)
+                              manifest=manifest, batch_stats=batch_stats)
 
 
 def train_streaming(X: Array, Y: Array, cfg: DiSMECConfig, out_dir: str,

@@ -5,7 +5,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.core.pruning import prune, to_block_sparse
+from repro.core.pruning import (prune, quantize_block_sparse,
+                                to_block_sparse)
 from repro.kernels.bsr_predict import ops, ref
 
 
@@ -61,3 +62,31 @@ def test_pruned_dismec_model_end_to_end(dismec_model, xmc_small_jnp):
     dense = Xte @ W.T
     np.testing.assert_allclose(np.asarray(out)[:, :W.shape[0]],
                                np.asarray(dense), rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+@pytest.mark.parametrize("n", [1, 5])
+@pytest.mark.parametrize("k", [1, 5])
+def test_bsr_predict_topk_masks_block_padding(dtype, n, k):
+    """Every real score is negative and the padding rows of the last row
+    block score exactly 0: the top-k must still be the real labels'
+    (ids < L), equal to lax.top_k over the dense scores."""
+    import jax
+
+    L, D = 40, 64                      # L pads to 48 at 16-row blocks
+    rng = np.random.default_rng(n * 10 + k)
+    W = -np.abs(rng.normal(size=(L, D))).astype(np.float32) - 0.1
+    x = jnp.asarray(np.abs(rng.normal(size=(n, D))) + 0.1, jnp.float32)
+    model = to_block_sparse(jnp.asarray(W), (16, 16))
+    if dtype == "int8":
+        model = quantize_block_sparse(model)
+        dense = np.asarray(model.dequantize().to_dense())[:L, :D]
+        vals, ids = ops.bsr_predict_int8_topk(x, model, k, n_labels=L)
+    else:
+        dense = W
+        vals, ids = ops.bsr_predict_topk(x, model, k, n_labels=L)
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(np.asarray(x) @ dense.T), k)
+    assert np.all(np.asarray(ids) < L)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(i_ref))
+    np.testing.assert_allclose(np.asarray(vals), np.asarray(v_ref),
+                               rtol=1e-4, atol=1e-4)
