@@ -234,6 +234,11 @@ def serve_xmc_server(args) -> None:
                   f"rejected={st['rejected']} "
                   f"(reject_rate={st['reject_rate']:.3f}) "
                   f"swaps={st['swaps']} "
+                  f"batches={st['batches']} (full={st['launched_full']} "
+                  f"deadline={st['launched_deadline']} "
+                  f"forced={st['launched_forced']}) "
+                  f"rows={st['rows_launched']} "
+                  f"padded={st['rows_padded']} "
                   f"p50={lat.get('p50_ms', float('nan')):.2f}ms "
                   f"p99={lat.get('p99_ms', float('nan')):.2f}ms "
                   f"queue_wait_p99={qw.get('p99_ms', float('nan')):.2f}ms")

@@ -15,7 +15,7 @@ compile. This module owns that machinery:
                           queued request's DEADLINE expires — the policy the
                           continuous-batching server (`serve.server`) runs.
   * `LatencyStats`      — per-request latency percentiles (p50/p90/p99) over
-                          enqueue -> completion spans.
+                          the most recent enqueue -> completion spans.
 
 The engines (`serve.engine` for LM decode, `serve.xmc.XMCEngine` for label
 queries) are thin loops around these primitives; `serve.server.XMCServer`
@@ -32,6 +32,12 @@ from typing import Iterator, Optional, Sequence
 import numpy as np
 
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
+# Why `next_batch` launched a batch: the largest bucket filled, the oldest
+# request's deadline expired, or the caller forced it (drain, shutdown).
+LAUNCH_REASONS = ("full", "deadline", "forced")
+# Samples a `LatencyStats` keeps: the most recent, so a server that runs
+# for days holds and sorts a bounded number.
+LATENCY_SAMPLES = 1 << 20
 
 
 def left_pad_tokens(requests: Sequence[np.ndarray],
@@ -80,6 +86,7 @@ class MicroBatch:
     bucket: int
     request_ids: list[int]
     row_counts: list[int]              # rows per request, in order
+    launch: str                        # why it launched: LAUNCH_REASONS
     arrivals: list[float] = dataclasses.field(default_factory=list)
                                        # enqueue timestamp per request piece
 
@@ -184,18 +191,23 @@ class MicroBatchQueue:
 
         Returns a padded micro-batch when (a) queued rows fill the largest
         bucket, (b) the oldest queued request has waited `max_delay_s` or
-        longer, or (c) `force` (drain/shutdown). Otherwise None — the
-        caller sleeps until the deadline and asks again.
+        longer, or (c) `force` (drain/shutdown), the first that holds
+        named in its `launch` ("full", "deadline", "forced"). Otherwise
+        None — the caller sleeps until the deadline and asks again.
         """
         if not self._pending:
             return None
         cap = self.buckets[-1]
-        if not force and self._rows < cap:
-            if max_delay_s is None:
-                return None
-            now = time.monotonic() if now is None else now
-            if now - self._pending[0].arrival < max_delay_s:
-                return None
+        if self._rows >= cap:
+            launch = "full"
+        elif max_delay_s is not None and (
+                (time.monotonic() if now is None else now)
+                - self._pending[0].arrival >= max_delay_s):
+            launch = "deadline"
+        elif force:
+            launch = "forced"
+        else:
+            return None
         group: list[_Pending] = [self._pending.popleft()]
         rows = group[0].x.shape[0]
         while self._pending and \
@@ -215,6 +227,7 @@ class MicroBatchQueue:
         return MicroBatch(x=x, bucket=bucket,
                           request_ids=[p.request_id for p in group],
                           row_counts=[p.x.shape[0] for p in group],
+                          launch=launch,
                           arrivals=[p.arrival for p in group])
 
     def drain(self) -> Iterator[MicroBatch]:
@@ -232,23 +245,21 @@ class LatencyStats:
     The primitive is `record_span(enqueue_ts, done_ts)` — one sample per
     request, measured from its own enqueue to its own completion, so queue
     wait is part of the number and percentiles are real order statistics.
-    `record(seconds, n_requests)` remains as the legacy aggregate API (one
-    pre-measured duration stamped onto n requests) as a thin wrapper.
+    Only the most recent `LATENCY_SAMPLES` are kept (a ring), so memory
+    and the cost of `summary()` stay bounded however long a server runs.
     """
 
     def __init__(self):
-        self._ms: list[float] = []
+        self._ms: collections.deque[float] = collections.deque(
+            maxlen=LATENCY_SAMPLES)
 
     def record_span(self, start: float, end: float) -> None:
         """One request's latency as its (enqueue, completion) timestamps."""
         self._ms.append((end - start) * 1e3)
 
-    def record(self, seconds: float, n_requests: int = 1):
-        for _ in range(n_requests):
-            self.record_span(0.0, seconds)
-
     @property
     def count(self) -> int:
+        """Samples held: every one recorded, up to `LATENCY_SAMPLES`."""
         return len(self._ms)
 
     def summary(self) -> dict[str, float]:

@@ -39,6 +39,16 @@ wraps an engine in an arrival-time-aware serving loop:
     `lifecycle.refresh.CheckpointWatcher` (`ModelRouter.watch`) drives it
     from a generation counter on disk.
 
+Tracing: the worker threads open `jax.profiler.TraceAnnotation` spans
+around each step of a micro-batch, so a profiler trace puts the host's
+work on the device's clock (free when no profiler runs). Dispatcher:
+`xmc.dispatch.idle` (queue empty), `xmc.dispatch.hold` (requests queued,
+neither fill nor deadline reached), then per batch `xmc.dispatch.form`,
+`.put`, `.launch` and `.handoff` (blocked while `max_inflight` batches are
+outstanding). Completion thread, per batch: `xmc.complete.device`,
+`.fetch`, `.resolve`. `stats()` counts launches by reason and the rows
+launched and padded.
+
 The batching policy itself lives in `serve.batching.MicroBatchQueue`
 (`next_batch`); the engine's synchronous `step()` path is untouched and
 remains bit-identical to this loop — same queue, same grouping, same
@@ -62,8 +72,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.serve.batching import LatencyStats
+from repro.serve.batching import LAUNCH_REASONS, LatencyStats
 from repro.serve.xmc import XMCEngine, XMCResult
 
 
@@ -120,6 +131,27 @@ class _Assembly:
 _STOP = object()          # completion-thread sentinel
 
 
+class _WaitSpan:
+    """The dispatcher's wait as profiler spans: one span per stretch of
+    one state (`xmc.dispatch.idle` / `.hold`), not one per wake-up."""
+
+    def __init__(self):
+        self._name: Optional[str] = None
+        self._span: Optional[TraceAnnotation] = None
+
+    def set(self, name: Optional[str]) -> None:
+        """Enter state `name` (None: stop waiting); a new span opens only
+        when the state changes."""
+        if name == self._name:
+            return
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        self._name, self._span = name, None
+        if name is not None:
+            self._span = TraceAnnotation(name)
+            self._span.__enter__()
+
+
 class XMCServer:
     """Arrival-time-aware continuous-batching loop over one `XMCEngine`.
 
@@ -166,7 +198,9 @@ class XMCServer:
         self.latency = LatencyStats()        # arrival -> completion
         self.queue_wait = LatencyStats()     # arrival -> device dispatch
         self.counters = {"accepted": 0, "rejected": 0, "completed": 0,
-                         "batches": 0, "swaps": 0}
+                         "batches": 0, "swaps": 0,
+                         **{f"launched_{r}": 0 for r in LAUNCH_REASONS},
+                         "rows_launched": 0, "rows_padded": 0}
         self.previous_engine: Optional[XMCEngine] = None  # rollback target
         self.last_swap: Optional[dict] = None   # timing of the latest swap
         self._cv = threading.Condition()
@@ -322,22 +356,34 @@ class XMCServer:
         launchable."""
         delay_s = self.max_batch_delay_ms / 1e3
         with self._cv:
-            mb = self.queue.next_batch(max_delay_s=delay_s, force=force)
+            if not self.queue:
+                return False
+            with TraceAnnotation("xmc.dispatch.form"):
+                mb = self.queue.next_batch(max_delay_s=delay_s, force=force)
             engine = self.engine     # captured with the batch: a concurrent
             # swap() must not tear one micro-batch across two models
         if mb is None:
             return False
         engine.ensure_warm(mb.bucket)
-        xb = jnp.asarray(mb.x)                   # host pack -> device put
+        with TraceAnnotation("xmc.dispatch.put"):
+            xb = jnp.asarray(mb.x)               # host pack -> device put
         t_dispatch = time.monotonic()
-        scores, labels = engine.backend.topk(xb)        # async dispatch
-        self.counters["batches"] += 1
-        self._inflight.put((mb, scores, labels, t_dispatch))
+        with TraceAnnotation("xmc.dispatch.launch"):
+            scores, labels = engine.backend.topk(xb)    # async dispatch
+        rows = sum(mb.row_counts)
+        c = self.counters
+        c["batches"] += 1
+        c[f"launched_{mb.launch}"] += 1
+        c["rows_launched"] += rows
+        c["rows_padded"] += mb.bucket - rows
+        with TraceAnnotation("xmc.dispatch.handoff"):
+            self._inflight.put((mb, scores, labels, t_dispatch))
         return True
 
     def _dispatch_loop(self) -> None:
         delay_s = self.max_batch_delay_ms / 1e3
         cap = self.queue.buckets[-1]
+        waiting = _WaitSpan()
         while True:
             with self._cv:
                 while True:
@@ -349,18 +395,33 @@ class XMCServer:
                     oldest = self.queue.oldest_arrival()
                     if oldest is not None and now - oldest >= delay_s:
                         break                    # deadline expired: launch
-                    wait = None if oldest is None else \
-                        max(delay_s - (now - oldest), 0.0)
+                    if oldest is None:
+                        waiting.set("xmc.dispatch.idle")
+                        wait = None
+                    else:
+                        waiting.set("xmc.dispatch.hold")
+                        wait = max(delay_s - (now - oldest), 0.0)
                     self._cv.wait(timeout=wait)
+                waiting.set(None)
                 stopping = self._stopping
             if not self._dispatch_once(force=stopping) and stopping:
                 break
         self._inflight.put(_STOP)
 
     def _complete_batch(self, mb, scores, labels, t_dispatch: float) -> None:
-        jax.block_until_ready(labels)
-        scores, labels = np.asarray(scores), np.asarray(labels)
+        with TraceAnnotation("xmc.complete.device"):
+            jax.block_until_ready(labels)
+        with TraceAnnotation("xmc.complete.fetch"):
+            scores, labels = np.asarray(scores), np.asarray(labels)
         t_done = time.monotonic()
+        with TraceAnnotation("xmc.complete.resolve"):
+            self._resolve_batch(mb, scores, labels, t_dispatch, t_done)
+
+    def _resolve_batch(self, mb, scores: np.ndarray, labels: np.ndarray,
+                       t_dispatch: float, t_done: float) -> None:
+        """Split a finished batch by request, assemble each request's
+        pieces, record its latency and queue wait, and resolve its
+        future once its last piece lands."""
         resolved = []
         with self._cv:
             for (rid, s), (_, l) in zip(mb.split(scores), mb.split(labels)):
@@ -403,7 +464,11 @@ class XMCServer:
     def stats(self) -> dict:
         """Counters + latency percentiles: `latency` is per-request
         arrival->completion, `queue_wait` is arrival->device-dispatch (what
-        admission control bounds)."""
+        admission control bounds), each over the most recent
+        `LATENCY_SAMPLES` requests. `launched_full` / `_deadline` /
+        `_forced` count batches by why they launched (they sum to
+        `batches`); `rows_launched` counts the requests' rows and
+        `rows_padded` the bucket rows added to them."""
         out = dict(self.counters)
         out["pending_requests"] = self.queue.pending_requests()
         accepted = out["accepted"] + out["rejected"]

@@ -89,6 +89,28 @@ def test_fifo_order_is_preserved_across_batches():
     assert server.counters["batches"] >= 2
 
 
+def test_stats_count_launch_reasons_and_padded_rows():
+    """Each batch counts under the one reason it launched for (the three
+    sum to `batches`); the requests' rows and the padding added to them
+    sum to the buckets launched."""
+    engine = _engine(buckets=(2, 4))
+    server = XMCServer(engine, max_batch_delay_ms=120_000.0, start=False)
+    for n in (3, 2):                  # 5 rows: [3] pads to 4, then [2]
+        server.submit(np.ones((n, 128), np.float32))
+    server.stop()                                    # inline force-drain
+    st = server.stats()
+    assert (st["batches"], st["launched_full"], st["launched_deadline"],
+            st["launched_forced"]) == (2, 1, 0, 1)
+    assert (st["rows_launched"], st["rows_padded"]) == (5, 1)
+
+    server = XMCServer(engine, max_batch_delay_ms=1.0)
+    server.submit(np.ones((1, 128), np.float32)).result(timeout=30)
+    server.stop()
+    st = server.stats()
+    assert (st["batches"], st["launched_deadline"]) == (1, 1)
+    assert (st["rows_launched"], st["rows_padded"]) == (1, 1)
+
+
 # ---------------------------------------------------------------------------
 # Admission control
 # ---------------------------------------------------------------------------

@@ -15,8 +15,8 @@ import jax.numpy as jnp
 
 from repro.core.pruning import BlockSparseModel, prune, to_block_sparse
 from repro.serve import BACKENDS, XMCEngine, make_backend
-from repro.serve.batching import (LatencyStats, MicroBatchQueue, pad_rows,
-                                  pick_bucket)
+from repro.serve.batching import (LATENCY_SAMPLES, LatencyStats,
+                                  MicroBatchQueue, pad_rows, pick_bucket)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -272,7 +272,8 @@ def test_split_request_counts_once_in_latency_stats():
 def test_next_batch_launch_policy():
     """Continuous-batching launch decision, with an injected clock: no
     launch before the deadline, launch at the deadline, immediate launch
-    when the largest bucket fills, and force for drain/shutdown."""
+    when the largest bucket fills, and force for drain/shutdown; each
+    batch names which of the three launched it."""
     q = MicroBatchQueue(buckets=(2, 4))
     assert q.next_batch(force=True) is None          # empty queue
     q.submit(np.ones((1, 3), np.float32), arrival=100.0)
@@ -283,17 +284,17 @@ def test_next_batch_launch_policy():
     # Deadline expired: ship the partial bucket.
     mb = q.next_batch(now=100.01, max_delay_s=0.002)
     assert mb is not None and mb.bucket == 2 and mb.row_counts == [1]
-    assert mb.arrivals == [100.0]
+    assert mb.arrivals == [100.0] and mb.launch == "deadline"
     # Fill launch: 4 rows >= largest bucket ships with no deadline check.
     for i in range(4):
         q.submit(np.ones((1, 3), np.float32), arrival=200.0 + i)
     mb = q.next_batch(now=200.0)                     # zero elapsed time
     assert mb is not None and mb.bucket == 4
-    assert mb.arrivals == [200.0, 201.0, 202.0, 203.0]
+    assert mb.arrivals == [200.0, 201.0, 202.0, 203.0] and mb.launch == "full"
     assert q.next_batch(now=200.0) is None
     # Force drains regardless of clock or fill.
     q.submit(np.ones((1, 3), np.float32), arrival=300.0)
-    assert q.next_batch(force=True) is not None
+    assert q.next_batch(force=True).launch == "forced"
 
 
 def test_queue_pending_and_arrival_accounting():
@@ -328,7 +329,7 @@ def test_reserve_id_shares_namespace_with_submit():
 def test_latency_stats_percentiles():
     s = LatencyStats()
     for ms in [1, 2, 3, 4, 100]:
-        s.record(ms / 1e3)
+        s.record_span(10.0, 10.0 + ms / 1e3)
     out = s.summary()
     assert out["count"] == 5
     assert out["p50_ms"] == pytest.approx(3.0)
@@ -337,15 +338,18 @@ def test_latency_stats_percentiles():
 
 def test_latency_stats_record_span_and_aggregate_wrapper():
     """record_span is the per-request primitive (enqueue -> completion
-    timestamps); the legacy record(seconds, n) API stamps one duration onto
-    n requests through the same samples list."""
+    timestamps); the summary aggregates only the most recent
+    LATENCY_SAMPLES of them, so a long-lived server's stats stay bounded
+    and the oldest samples fall out first."""
     s = LatencyStats()
-    s.record_span(10.0, 10.004)                      # 4 ms span
-    s.record(0.002, n_requests=3)                    # 3 aggregate samples
+    for _ in range(3):
+        s.record_span(0.0, 1.0)                      # 1,000 ms, oldest
+    for _ in range(LATENCY_SAMPLES):
+        s.record_span(10.0, 10.004)                  # 4 ms
     out = s.summary()
-    assert s.count == 4 and out["count"] == 4
-    assert out["p50_ms"] == pytest.approx(2.0)
-    assert max(np.asarray(s._ms)) == pytest.approx(4.0)
+    assert s.count == LATENCY_SAMPLES and out["count"] == LATENCY_SAMPLES
+    assert out["p99_ms"] == pytest.approx(4.0)
+    assert out["mean_ms"] == pytest.approx(4.0)
 
 
 def test_step_latency_includes_queue_wait():
