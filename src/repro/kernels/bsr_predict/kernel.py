@@ -7,18 +7,23 @@ blocks are dropped at model-conversion time (core/pruning.to_block_sparse),
 and this kernel iterates ONLY over surviving blocks — compute and HBM traffic
 scale with block density, not with L x D.
 
-Mechanics: one grid step per packed nonzero block, ordered row-major. The
-block's (row, col) coordinates are scalar-prefetched so BlockSpec index_maps
-can steer both the x-tile fetch (col) and the output-tile revisit (row).
-Because blocks of one label-row are adjacent in the packing, the output tile
-(n, bl) stays resident in VMEM for the whole row and is written back once.
-
-VMEM (f32): x tile n*bd + W block bl*bd + out tile n*bl; for n = 256,
-bl = bd = 128 that is 128 KB + 64 KB + 128 KB — far under budget, so wide
-request batches are fine.
+Mechanics: each grid step takes `GROUP` consecutive packed blocks, so
+the fixed cost of a grid step is paid once per group and the W DMA of a
+step is GROUP * bl * bd elements (1 MiB of fp32 128 x 128 blocks), read
+from the packed array as it is. A row tile of x (rows, Dp) and of the
+scores (rows, Lp) stays resident in VMEM while the grid walks every
+group: x is fetched once and the scores are written back once per tile.
+The tile holds all n rows where they fit `VMEM_BUDGET`; wider launches
+take several tiles, one pass over the blocks each. The blocks' (row,
+col) coordinates reach the kernel as SMEM windows of 1,024 entries,
+whatever the block count. Each block takes one (rows, bd) x (bl, bd)^T
+dot added into its label row's score tile in packed order, the same
+float32 additions as one grid step per block.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -29,21 +34,90 @@ from repro.compat import resolve_interpret
 
 DEFAULT_BLOCK = (128, 128)
 
+# Bytes of VMEM one row tile of x (rows, Dp) and of the fp32 scores
+# (rows, Lp) may fill, rows counted in whole sublane tiles of 8: most of a
+# TPU v5e core's 128 MiB, so that at Wiki10-31K half (Dp 102,016,
+# Lp 15,488) a tile holds up to 208 rows and a 128-row launch reads the
+# blocks once.
+VMEM_BUDGET = 96 << 20
+# Packed blocks per grid step: 1 MiB of fp32 128 x 128 blocks, the
+# fastest of 4-64 at Wiki10-31K half on a TPU v5e.
+GROUP = 16
+# block_rows / block_cols reach the kernel as SMEM windows of this many
+# entries (the 1-D int32 tiling XLA gives them); a group never straddles
+# two windows because GROUP divides it.
+_SMEM_WINDOW = 1024
 
-def _bsr_kernel(rows_ref, cols_ref, x_ref, blk_ref, o_ref):
-    """Grid step k: o[:, rows[k]] += x[:, cols[k]] @ blocks[k]^T."""
-    del cols_ref
-    k = pl.program_id(0)
-    is_new_row = jnp.logical_or(
-        k == 0, rows_ref[k] != rows_ref[jnp.maximum(k - 1, 0)])
 
-    @pl.when(is_new_row)
+def _resident_bytes(rows: int, Lp: int, Dp: int) -> int:
+    """VMEM a row tile keeps resident: x and the fp32 scores, rows padded
+    to the 8-row sublane tile."""
+    return 4 * (-(-rows // 8) * 8) * (Dp + Lp)
+
+
+def row_tile(n: int, Lp: int, Dp: int) -> int:
+    """Rows of x and the scores held in VMEM during one pass over the
+    packed blocks: all n where they fit `VMEM_BUDGET`, else the fewest
+    passes of equal tiles, each a multiple of 8 rows within the budget."""
+    if _resident_bytes(n, Lp, Dp) <= VMEM_BUDGET:
+        return n
+    most = max(8, VMEM_BUDGET // (4 * (Dp + Lp)) // 8 * 8)
+    passes = -(-n // most)
+    per_pass = -(-n // passes)
+    return -(-per_pass // 8) * 8
+
+
+def _bsr_kernel(rows_ref, cols_ref, x_ref, blk_ref, o_ref, *, n_blocks):
+    """Grid step (i, k), row tile i: packed blocks [k*G, (k+1)*G), each
+    added as o[:, rows[p]] += x[:, cols[p]] @ blocks[p]^T in packed order.
+
+    rows_ref / cols_ref hold the SMEM window of entries the group lies in;
+    o_ref is the tile's (rows, Lp) scores, zeroed at the first step. A
+    group inside one label row sums its dots into one tile held in
+    registers and stores it once: the same float32 additions, in the same
+    order, as a load-add-store per block. Other groups, and the last,
+    partial one, update their tiles block by block in a loop that stops
+    at n_blocks.
+    """
+    k = pl.program_id(1)
+    group, bl, bd = blk_ref.shape
+    window = rows_ref.shape[0]
+    base = (k * group) % window
+
+    @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    def dot(g):
+        c = pl.multiple_of(cols_ref[base + g] * bd, bd)
+        return jax.lax.dot_general(
+            x_ref[:, pl.ds(c, bd)].astype(jnp.float32),
+            blk_ref[g].astype(jnp.float32),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    def tile(g):
+        return pl.ds(pl.multiple_of(rows_ref[base + g] * bl, bl), bl)
+
+    last = jnp.minimum(base + group - 1, window - 1)
+    one_row = jnp.logical_and(k < n_blocks // group,
+                              rows_ref[base] == rows_ref[last])
+
+    @pl.when(one_row)
+    def _one_row():
+        acc = o_ref[:, tile(0)]
+        for g in range(group):
+            acc += dot(g)
+        o_ref[:, tile(0)] = acc
+
+    @pl.when(jnp.logical_not(one_row))
+    def _by_block():
+        def add(g, carry):
+            o_ref[:, tile(g)] += dot(g)
+            return carry
+
+        # A loop, not 16 gated copies: this path is rare on long label
+        # rows, and each unrolled copy costs lowering time per bucket.
+        jax.lax.fori_loop(0, jnp.minimum(group, n_blocks - k * group), add, 0)
 
 
 def bsr_predict_pallas(x: jax.Array, blocks: jax.Array, block_rows: jax.Array,
@@ -51,30 +125,50 @@ def bsr_predict_pallas(x: jax.Array, blocks: jax.Array, block_rows: jax.Array,
                        *, interpret: bool | None = None) -> jax.Array:
     """x (n, Dp), blocks (nb, bl, bd) row-major packed -> scores (n, Lp).
 
-    Row-blocks with no surviving blocks are never visited; ops.py masks them.
+    Grid (row tiles, groups of `GROUP` blocks; fewer where nb < GROUP).
+    Scores of row-blocks with no surviving blocks are zero; ops.py masks
+    them all the same.
     """
-    n = x.shape[0]
+    n, Dp = x.shape
     nb, bl, bd = blocks.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((n, bd), lambda k, rows, cols: (0, cols[k])),
-                  pl.BlockSpec((1, bl, bd), lambda k, rows, cols: (k, 0, 0))],
-        out_specs=pl.BlockSpec((n, bl), lambda k, rows, cols: (0, rows[k])),
-    )
-    return pl.pallas_call(
-        _bsr_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, n_row_blocks * bl), jnp.float32),
+    Lp = n_row_blocks * bl
+    rows = row_tile(n, Lp, Dp)
+    passes = pl.cdiv(n, rows)
+    if passes * rows != n:
+        x = jnp.pad(x, ((0, passes * rows - n), (0, 0)))
+    group = min(GROUP, nb)
+    window = min(_SMEM_WINDOW, nb)
+    meta = pl.BlockSpec((window,), lambda i, k: (k * group // window,),
+                        memory_space=pltpu.SMEM)
+    resident = pl.Buffered(1)
+    w_bytes = group * bl * bd * blocks.dtype.itemsize
+    scores = pl.pallas_call(
+        functools.partial(_bsr_kernel, n_blocks=nb),
+        grid=(passes, pl.cdiv(nb, group)),
+        in_specs=[meta, meta,
+                  pl.BlockSpec((rows, Dp), lambda i, k: (i, 0),
+                               pipeline_mode=resident),
+                  pl.BlockSpec((group, bl, bd), lambda i, k: (k, 0, 0))],
+        out_specs=pl.BlockSpec((rows, Lp), lambda i, k: (i, 0),
+                               pipeline_mode=resident),
+        out_shape=jax.ShapeDtypeStruct((passes * rows, Lp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=(_resident_bytes(rows, Lp, Dp) + 2 * w_bytes
+                              + (8 << 20))),
         interpret=resolve_interpret(interpret),
     )(block_rows, block_cols, x, blocks)
+    return scores if passes * rows == n else scores[:n]
 
 
 def _bsr_int8_kernel(rows_ref, cols_ref, scales_ref, x_ref, blk_ref, o_ref):
-    """Int8 variant of `_bsr_kernel`: the packed block arrives as int8,
-    is widened to fp32 in-register, and the per-block scale is applied to
-    the fp32 partial product — one scalar multiply per output tile instead
-    of bl*bd dequant multiplies, with identical accumulation order to the
-    gathered int8 kernel (the bit-for-bit full-coverage contract)."""
+    """Int8 exhaustive kernel, one grid step per packed block (row, col
+    scalar-prefetched, the output tile revisited along a label row): the
+    packed block arrives as int8, is widened to fp32 in-register, and the
+    per-block scale is applied to the fp32 partial product — one scalar
+    multiply per output tile instead of bl*bd dequant multiplies, with
+    identical accumulation order to the gathered int8 kernel (the
+    bit-for-bit full-coverage contract)."""
     del cols_ref
     k = pl.program_id(0)
     is_new_row = jnp.logical_or(

@@ -24,7 +24,8 @@ from repro.kernels.bsr_predict.kernel import (bsr_predict_gather_int8_pallas,
                                               bsr_predict_gather_pq_int8_pallas,
                                               bsr_predict_gather_pq_pallas,
                                               bsr_predict_int8_pallas,
-                                              bsr_predict_pallas)
+                                              bsr_predict_pallas,
+                                              row_tile)
 
 # Score of a masked (padding) label: loses to every real score, finite so
 # no -inf reaches a comparison.
@@ -336,14 +337,14 @@ def dense_flops(model: BlockSparseModel, n: int) -> int:
 
 
 def predict_bytes(model: BlockSparseModel, n: int) -> int:
-    """Bytes the exhaustive fp32 predict must move through HBM: every
-    packed block once, plus x streamed per row block, plus the output."""
+    """Bytes the exhaustive fp32 predict moves through HBM: every packed
+    block once per row tile of the launch (`kernel.row_tile`), x once,
+    and the output once."""
     bl, bd = model.block_shape
     Lp, Dp = model.shape
-    weights = 4 * model.n_blocks * bl * bd
-    x_bytes = 4 * n * Dp * (Lp // bl)        # x re-read per row block
-    out = 4 * n * Lp
-    return weights + x_bytes + out
+    passes = -(-n // row_tile(n, Lp, Dp))
+    weights = 4 * model.n_blocks * bl * bd * passes
+    return weights + 4 * n * Dp + 4 * n * Lp
 
 
 def predict_bytes_int8(model, n: int) -> int:

@@ -77,6 +77,30 @@ def test_bsr_exhaustive(chip, n, dtype):
             ((n, DP), F32), ((NB, BL, BD), I8), ((NB,), F32), *meta)
 
 
+# The wiki10-31k-half serving cell's model (121 x 797 packed blocks) and
+# full Wiki10-31K's (242 x 797).
+HALF_NB, HALF_LP = 96437, 15488
+FULL_NB, FULL_LP = 192874, 30976
+
+
+@pytest.mark.parametrize("nb,lp,n", [
+    (HALF_NB, HALF_LP, 8), (HALF_NB, HALF_LP, 32),
+    (HALF_NB, HALF_LP, 128),
+    (HALF_NB, HALF_LP, 208),             # the widest launch in one row tile
+    (HALF_NB, HALF_LP, 256),             # two row tiles of 128
+    (FULL_NB, FULL_LP, 32), (FULL_NB, FULL_LP, 128),
+    (1, LP, 16),                         # the fully pruned sentinel model
+    (20, LP, 16),                        # under one group's 16 blocks twice
+    (1000, LP, 16)])                     # under one SMEM window
+def test_bsr_exhaustive_wiki10(chip, nb, lp, n):
+    """The exhaustive fp32 kernel at Wiki10-31K width: the VMEM limit a row
+    tile asks for must be granted, and the block coordinates' SMEM windows
+    and the 16-block W window must compile however few blocks there are."""
+    _compile(chip, lambda x, b, r, c: bsr.bsr_predict_pallas(
+        x, b, r, c, lp // BL, interpret=False),
+        ((n, DP), F32), ((nb, BL, BD), F32), ((nb,), I32), ((nb,), I32))
+
+
 @pytest.mark.parametrize("n", [1, 16])
 @pytest.mark.parametrize("dtype", ["fp32", "int8"])
 def test_bsr_gather(chip, n, dtype):
