@@ -7,18 +7,27 @@ blocks are dropped at model-conversion time (core/pruning.to_block_sparse),
 and this kernel iterates ONLY over surviving blocks — compute and HBM traffic
 scale with block density, not with L x D.
 
-Mechanics: each grid step takes `GROUP` consecutive packed blocks, so
-the fixed cost of a grid step is paid once per group and the W DMA of a
-step is GROUP * bl * bd elements (1 MiB of fp32 128 x 128 blocks), read
-from the packed array as it is. A row tile of x (rows, Dp) and of the
-scores (rows, Lp) stays resident in VMEM while the grid walks every
-group: x is fetched once and the scores are written back once per tile.
-The tile holds all n rows where they fit `VMEM_BUDGET`; wider launches
-take several tiles, one pass over the blocks each. The blocks' (row,
-col) coordinates reach the kernel as SMEM windows of 1,024 entries,
-whatever the block count. Each block takes one (rows, bd) x (bl, bd)^T
-dot added into its label row's score tile in packed order, the same
-float32 additions as one grid step per block.
+Mechanics: the packed blocks are read `GROUP` at a time (1 MiB of fp32
+128 x 128 blocks), in packed order, and each block takes one
+(rows, bd) x (bl, bd)^T dot added into its label row's scores in packed
+order. What stays in VMEM is chosen from the launch's shapes alone
+(`streams_scores`), by this rule:
+
+  resident   x (n, Dp) and the scores (n, Lp) of all n rows fit
+             `VMEM_BUDGET`: both stay resident while the grid walks every
+             group, x fetched once, the scores written back once.
+  streamed   else, where x of all n rows fits: x stays resident and the
+             grid walks groups of `STEP_ROW_BLOCKS` label row blocks, each
+             step writing its (n, STEP_ROW_BLOCKS * bl) score slice once.
+             The packed blocks and their column ids are streamed in by
+             DMA, double-buffered, every block once; `row_ptr` is
+             scalar-prefetched. Each label row sums its dots in registers.
+  row tiles  else: the resident layout over equal row tiles of x and the
+             scores (`row_tile`), one pass over the blocks each.
+
+Every layout adds the same per-block dots in the same order into zeroed
+scores, so they return the same bits. `weight_passes` says how many
+times a launch reads the blocks: one, except in row tiles.
 """
 
 from __future__ import annotations
@@ -34,11 +43,13 @@ from repro.compat import resolve_interpret
 
 DEFAULT_BLOCK = (128, 128)
 
-# Bytes of VMEM one row tile of x (rows, Dp) and of the fp32 scores
-# (rows, Lp) may fill, rows counted in whole sublane tiles of 8: most of a
-# TPU v5e core's 128 MiB, so that at Wiki10-31K half (Dp 102,016,
-# Lp 15,488) a tile holds up to 208 rows and a 128-row launch reads the
-# blocks once.
+# Bytes of VMEM a launch's resident arrays may fill, rows counted in whole
+# sublane tiles of 8: x and the fp32 scores of a row tile in the resident
+# layout, x and two score slices in the streamed one. Most of a TPU v5e
+# core's 128 MiB, so that at Wiki10-31K half (Dp 102,016, Lp 15,488) one
+# tile holds up to 208 rows and a 128-row launch reads the blocks once; at
+# LF-AmazonTitles-1.3M (Dp 768, Lp 1,305,344) the scores of 16 rows fit,
+# and wider launches stream them.
 VMEM_BUDGET = 96 << 20
 # Packed blocks per grid step: 1 MiB of fp32 128 x 128 blocks, the
 # fastest of 4-64 at Wiki10-31K half on a TPU v5e.
@@ -47,12 +58,46 @@ GROUP = 16
 # entries (the 1-D int32 tiling XLA gives them); a group never straddles
 # two windows because GROUP divides it.
 _SMEM_WINDOW = 1024
+# Label row blocks per grid step of the streamed layout: a 4,096-label
+# score slice at 128-row blocks.
+STEP_ROW_BLOCKS = 32
+# The streamed layout takes a label row's blocks this many at a time, as
+# independent dots. One at a time, each dot waits on the last: at
+# LF-AmazonTitles-1.3M's 6-block rows a launch took 8.0 ms against 7.0 ms
+# at 4-16 at a time on a TPU v5e (fetches one chunk ahead in both).
+_ROW_UNROLL = 8
+# Chunks (and column windows) the streamed layout keeps in flight ahead
+# of the one it reads: a launch there took 5.47 / 5.34 / 5.34 ms at 1 / 2
+# / 3 (n = 1; 9.12 / 8.12 / 7.62 ms at n = 256) against 4.90 ms for the
+# blocks at 819 GB/s.
+_STREAM_AHEAD = 2
 
 
 def _resident_bytes(rows: int, Lp: int, Dp: int) -> int:
     """VMEM a row tile keeps resident: x and the fp32 scores, rows padded
     to the 8-row sublane tile."""
     return 4 * (-(-rows // 8) * 8) * (Dp + Lp)
+
+
+def _streamed_bytes(n: int, Dp: int, bl: int) -> int:
+    """VMEM the streamed layout keeps: x and two score slices in flight,
+    rows padded to the 8-row sublane tile."""
+    return 4 * (-(-n // 8) * 8) * (Dp + 2 * STEP_ROW_BLOCKS * bl)
+
+
+def streams_scores(n: int, Lp: int, Dp: int, bl: int) -> bool:
+    """Whether an n-row launch takes the streamed layout: the scores of all
+    n rows do not fit `VMEM_BUDGET` beside x, and x alone (with two score
+    slices) does."""
+    return (_resident_bytes(n, Lp, Dp) > VMEM_BUDGET
+            and _streamed_bytes(n, Dp, bl) <= VMEM_BUDGET)
+
+
+def weight_passes(n: int, Lp: int, Dp: int, bl: int) -> int:
+    """Passes an n-row launch makes over the packed blocks."""
+    if streams_scores(n, Lp, Dp, bl):
+        return 1
+    return -(-n // row_tile(n, Lp, Dp))
 
 
 def row_tile(n: int, Lp: int, Dp: int) -> int:
@@ -120,18 +165,185 @@ def _bsr_kernel(rows_ref, cols_ref, x_ref, blk_ref, o_ref, *, n_blocks):
         jax.lax.fori_loop(0, jnp.minimum(group, n_blocks - k * group), add, 0)
 
 
+def _bsr_stream_kernel(ptr_ref, x_ref, blk_hbm, cols_hbm, o_ref, blk_buf,
+                       cols_buf, sems, chunk_ref, *, n_blocks, n_row_blocks):
+    """Grid step i: label row blocks [i*S, (i+1)*S), S = STEP_ROW_BLOCKS.
+
+    Each row block r sums x[:, cols[p]] @ blocks[p]^T over its packed
+    blocks p in [row_ptr[r], row_ptr[r+1]) in registers, from zero, in
+    packed order, and stores its (n, bl) scores once into the step's slice
+    o_ref. Row blocks past the last (a partial final step) and row blocks
+    with no packed block store zeros. A row's blocks are taken
+    `_ROW_UNROLL` at a time: that many independent dots (the last of the
+    row standing in past its end), then added in order, each past the
+    end as an exact zero, so the MXUs overlap the dots of short rows.
+
+    The packed blocks stream through blk_buf in chunks of `group`, chunk c
+    holding blocks [c*group, (c+1)*group) (the last clamped to end at
+    n_blocks), and their column ids through cols_buf in aligned windows.
+    Each has `_STREAM_AHEAD` + 2 slots: the one read, the one before it (a
+    run of a row's blocks may straddle the two), and `_STREAM_AHEAD` in
+    flight; entering chunk c starts chunk c + _STREAM_AHEAD before it
+    waits for c, so the next fetch is always under way. chunk_ref holds
+    the chunk the walk is in: a chunk that straddles two steps is fetched
+    and waited for once. The last step waits for any fetch the walk did
+    not consume.
+    """
+    i = pl.program_id(0)
+    step_rows = o_ref.shape[1] // blk_buf.shape[2]
+    slots, group, bl, bd = blk_buf.shape
+    window = cols_buf.shape[0] // slots
+    n_chunks = pl.cdiv(n_blocks, group)
+    n_windows = cols_hbm.shape[0] // window
+
+    def first(c):
+        return jnp.minimum(c * group, n_blocks - group)
+
+    def chunk_copy(c):
+        return pltpu.make_async_copy(blk_hbm.at[pl.ds(first(c), group)],
+                                     blk_buf.at[c % slots],
+                                     sems.at[0, c % slots])
+
+    def window_copy(w):
+        return pltpu.make_async_copy(
+            cols_hbm.at[pl.ds(pl.multiple_of(w * window, window), window)],
+            cols_buf.at[pl.ds(pl.multiple_of(w % slots * window, window),
+                              window)],
+            sems.at[1, w % slots])
+
+    @pl.when(i == 0)
+    def _start():
+        chunk_ref[0] = -1
+        for a in range(min(_STREAM_AHEAD, n_chunks)):
+            chunk_copy(a).start()
+        for a in range(min(_STREAM_AHEAD, n_windows)):
+            window_copy(a).start()
+
+    def enter(c):
+        @pl.when(c > chunk_ref[0])
+        def _enter():
+            @pl.when(c + _STREAM_AHEAD < n_chunks)
+            def _next_chunk():
+                chunk_copy(c + _STREAM_AHEAD).start()
+
+            chunk_copy(c).wait()
+            w = c * group // window
+
+            @pl.when(c * group % window == 0)
+            def _window():
+                @pl.when(w + _STREAM_AHEAD < n_windows)
+                def _next_window():
+                    window_copy(w + _STREAM_AHEAD).start()
+
+                window_copy(w).wait()
+
+            chunk_ref[0] = c
+
+    def dot(p):
+        c = p // group
+        col = cols_buf[p // window % slots * window + p % window]
+        c0 = pl.multiple_of(col * bd, bd)
+        return jax.lax.dot_general(
+            x_ref[:, pl.ds(c0, bd)].astype(jnp.float32),
+            blk_buf[c % slots, p - first(c)].astype(jnp.float32),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    def row(t, carry):
+        r = i * step_rows + t
+        lo = ptr_ref[jnp.minimum(r, n_row_blocks)]
+        hi = ptr_ref[jnp.minimum(r + 1, n_row_blocks)]
+
+        def run(j, acc):
+            base = lo + j * _ROW_UNROLL
+            last = jnp.minimum(base + _ROW_UNROLL, hi) - 1
+            enter(base // group)
+            enter(last // group)
+            dots = [dot(jnp.minimum(base + u, last))
+                    for u in range(_ROW_UNROLL)]
+            for u, d in enumerate(dots):
+                acc = acc + jnp.where(base + u <= last, d, 0.0)
+            return acc
+
+        acc = jax.lax.fori_loop(
+            0, pl.cdiv(hi - lo, _ROW_UNROLL), run,
+            jnp.zeros((x_ref.shape[0], bl), jnp.float32))
+        o_ref[:, pl.ds(pl.multiple_of(t * bl, bl), bl)] = acc
+        return carry
+
+    jax.lax.fori_loop(0, step_rows, row, 0)
+
+    # A model whose row_ptr leaves packed blocks unvisited (the fully
+    # pruned one keeps a sentinel block in no row) still has chunks and
+    # windows in flight: wait for them before the kernel ends.
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _drain():
+        c = chunk_ref[0]
+        w = jnp.where(c >= 0, c * group // window, -1)
+        for a in range(1, _STREAM_AHEAD + 1):
+            @pl.when(c + a < n_chunks)
+            def _chunk():
+                chunk_copy(c + a).wait()
+
+            @pl.when(w + a < n_windows)
+            def _window():
+                window_copy(w + a).wait()
+
+
+def _bsr_streamed(x, blocks, block_cols, row_ptr, n_row_blocks, interpret):
+    """The streamed layout: x resident, score slices of STEP_ROW_BLOCKS
+    label row blocks written once each, blocks and column ids by DMA."""
+    n, Dp = x.shape
+    nb, bl, bd = blocks.shape
+    group = min(GROUP, nb)
+    slots = _STREAM_AHEAD + 2
+    cols = jnp.pad(block_cols, (0, -nb % _SMEM_WINDOW))
+    w_bytes = group * bl * bd * blocks.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_bsr_stream_kernel, n_blocks=nb,
+                          n_row_blocks=n_row_blocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(n_row_blocks, STEP_ROW_BLOCKS),),
+            in_specs=[pl.BlockSpec((n, Dp), lambda i, ptr: (0, 0),
+                                   pipeline_mode=pl.Buffered(1)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((n, STEP_ROW_BLOCKS * bl),
+                                   lambda i, ptr: (0, i)),
+            scratch_shapes=[
+                pltpu.VMEM((slots, group, bl, bd), blocks.dtype),
+                pltpu.SMEM((slots * _SMEM_WINDOW,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2, slots)),
+                pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((n, n_row_blocks * bl), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(_streamed_bytes(n, Dp, bl)
+                              + slots * w_bytes + (8 << 20))),
+        interpret=resolve_interpret(interpret),
+    )(row_ptr, x, blocks, cols)
+
+
 def bsr_predict_pallas(x: jax.Array, blocks: jax.Array, block_rows: jax.Array,
                        block_cols: jax.Array, n_row_blocks: int,
-                       *, interpret: bool | None = None) -> jax.Array:
-    """x (n, Dp), blocks (nb, bl, bd) row-major packed -> scores (n, Lp).
+                       *, row_ptr: jax.Array,
+                       interpret: bool | None = None) -> jax.Array:
+    """x (n, Dp), blocks (nb, bl, bd) row-major packed, with their label
+    row block and column block ids and row_ptr (n_row_blocks + 1,) ->
+    scores (n, Lp).
 
-    Grid (row tiles, groups of `GROUP` blocks; fewer where nb < GROUP).
-    Scores of row-blocks with no surviving blocks are zero; ops.py masks
-    them all the same.
+    The layout follows `streams_scores`. Resident and row tiles: grid (row
+    tiles, groups of `GROUP` blocks; fewer where nb < GROUP), reading
+    block_rows. Streamed: grid (groups of `STEP_ROW_BLOCKS` label row
+    blocks,), reading row_ptr. Scores of row-blocks with no surviving
+    blocks are zero; ops.py masks them all the same.
     """
     n, Dp = x.shape
     nb, bl, bd = blocks.shape
     Lp = n_row_blocks * bl
+    if streams_scores(n, Lp, Dp, bl):
+        return _bsr_streamed(x, blocks, block_cols, row_ptr, n_row_blocks,
+                             interpret)
     rows = row_tile(n, Lp, Dp)
     passes = pl.cdiv(n, rows)
     if passes * rows != n:

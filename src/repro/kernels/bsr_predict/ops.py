@@ -3,7 +3,9 @@
 `bsr_predict` yields the dense (n, Lp) score matrix; `bsr_predict_topk`
 reduces it with `jax.lax.top_k` into the serving entry point used by
 `repro.serve.xmc.BsrBackend` — scores never leave the padded block
-coordinate system before being reduced to k candidates.
+coordinate system before being reduced to k candidates. The masks and the
+top-k run under `jax.named_scope("xmc.topk")`, so a device trace tells
+their time from the kernel's.
 
 `bsr_predict_gather` / `bsr_predict_gather_topk` are the shortlist-gated
 variants (serve/shortlist.py): given a per-batch list of selected row
@@ -25,7 +27,7 @@ from repro.kernels.bsr_predict.kernel import (bsr_predict_gather_int8_pallas,
                                               bsr_predict_gather_pq_pallas,
                                               bsr_predict_int8_pallas,
                                               bsr_predict_pallas,
-                                              row_tile)
+                                              weight_passes)
 
 # Score of a masked (padding) label: loses to every real score, finite so
 # no -inf reaches a comparison.
@@ -62,6 +64,17 @@ def _mask_empty_row_blocks(out: jax.Array, model) -> jax.Array:
     return jnp.where(row_mask[None, :], out, 0.0)
 
 
+def _kernel_scores(x: jax.Array, model: BlockSparseModel,
+                   interpret: bool | None) -> jax.Array:
+    """The kernel's (n, Lp) scores, x padded to the model's width; row
+    blocks with no surviving block are not yet masked."""
+    Lp, Dp = model.shape
+    bl, bd = model.block_shape
+    return bsr_predict_pallas(_pad_features(x, model), model.blocks,
+                              model.block_rows, model.block_cols, Lp // bl,
+                              row_ptr=model.row_ptr, interpret=interpret)
+
+
 def bsr_predict(x: jax.Array, model: BlockSparseModel,
                 *, interpret: bool | None = None) -> jax.Array:
     """Scores (n, L) for a batch against a block-sparse model.
@@ -70,12 +83,7 @@ def bsr_predict(x: jax.Array, model: BlockSparseModel,
     request is WIDER than the model) and zeroes out label row-blocks that
     have no surviving blocks (never visited by the kernel).
     """
-    Lp, Dp = model.shape
-    bl, bd = model.block_shape
-    x = _pad_features(x, model)
-    out = bsr_predict_pallas(x, model.blocks, model.block_rows,
-                             model.block_cols, Lp // bl, interpret=interpret)
-    return _mask_empty_row_blocks(out, model)
+    return _mask_empty_row_blocks(_kernel_scores(x, model, interpret), model)
 
 
 def bsr_predict_int8(x: jax.Array, model: Int8BlockSparseModel,
@@ -103,12 +111,14 @@ def bsr_predict_topk(x: jax.Array, model: BlockSparseModel, k: int,
     top-k so a block-padded model never serves phantom labels. Fully
     pruned real labels keep their exact-zero score, matching the dense path.
     """
-    scores = bsr_predict(x, model, interpret=interpret)
-    Lp = scores.shape[1]
-    if n_labels is not None and n_labels < Lp:
-        ids = jnp.arange(Lp)
-        scores = jnp.where(ids[None, :] < n_labels, scores, NEG_INF)
-    return jax.lax.top_k(scores, k)
+    scores = _kernel_scores(x, model, interpret)
+    with jax.named_scope("xmc.topk"):
+        scores = _mask_empty_row_blocks(scores, model)
+        Lp = scores.shape[1]
+        if n_labels is not None and n_labels < Lp:
+            ids = jnp.arange(Lp)
+            scores = jnp.where(ids[None, :] < n_labels, scores, NEG_INF)
+        return jax.lax.top_k(scores, k)
 
 
 def bsr_predict_int8_topk(x: jax.Array, model: Int8BlockSparseModel, k: int,
@@ -338,11 +348,11 @@ def dense_flops(model: BlockSparseModel, n: int) -> int:
 
 def predict_bytes(model: BlockSparseModel, n: int) -> int:
     """Bytes the exhaustive fp32 predict moves through HBM: every packed
-    block once per row tile of the launch (`kernel.row_tile`), x once,
+    block once per pass the launch makes (`kernel.weight_passes`), x once,
     and the output once."""
     bl, bd = model.block_shape
     Lp, Dp = model.shape
-    passes = -(-n // row_tile(n, Lp, Dp))
+    passes = weight_passes(n, Lp, Dp, bl)
     weights = 4 * model.n_blocks * bl * bd * passes
     return weights + 4 * n * Dp + 4 * n * Lp
 

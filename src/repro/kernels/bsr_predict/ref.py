@@ -14,6 +14,16 @@ def bsr_predict(x: jax.Array, model: BlockSparseModel) -> jax.Array:
     return x.astype(jnp.float32) @ W.T.astype(jnp.float32)
 
 
+def dense_topk(x: jax.Array, W: jax.Array, k: int):
+    """The plain float32 reference of serving: scores = x @ W^T at the
+    highest matmul precision over W's rows (the real labels), then their
+    k best as (scores, label ids), each (n, k). No kernel, bucket or
+    server."""
+    scores = jnp.dot(x.astype(jnp.float32), W.astype(jnp.float32).T,
+                     precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.top_k(scores, k)
+
+
 def bsr_predict_int8(x: jax.Array, model: Int8BlockSparseModel) -> jax.Array:
     """Oracle for the int8 kernel: dequantize every block to fp32 (the
     exact values the kernel reconstructs in-register) then dense matmul."""

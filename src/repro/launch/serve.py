@@ -239,6 +239,7 @@ def serve_xmc_server(args) -> None:
                   f"forced={st['launched_forced']}) "
                   f"rows={st['rows_launched']} "
                   f"padded={st['rows_padded']} "
+                  f"weight_passes={st['weight_passes']} "
                   f"p50={lat.get('p50_ms', float('nan')):.2f}ms "
                   f"p99={lat.get('p99_ms', float('nan')):.2f}ms "
                   f"queue_wait_p99={qw.get('p99_ms', float('nan')):.2f}ms")

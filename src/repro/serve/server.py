@@ -46,8 +46,8 @@ work on the device's clock (free when no profiler runs). Dispatcher:
 neither fill nor deadline reached), then per batch `xmc.dispatch.form`,
 `.put`, `.launch` and `.handoff` (blocked while `max_inflight` batches are
 outstanding). Completion thread, per batch: `xmc.complete.device`,
-`.fetch`, `.resolve`. `stats()` counts launches by reason and the rows
-launched and padded.
+`.fetch`, `.resolve`. `stats()` counts launches by reason, the rows
+launched and padded, and the passes the launches made over the weights.
 
 The batching policy itself lives in `serve.batching.MicroBatchQueue`
 (`next_batch`); the engine's synchronous `step()` path is untouched and
@@ -200,7 +200,8 @@ class XMCServer:
         self.counters = {"accepted": 0, "rejected": 0, "completed": 0,
                          "batches": 0, "swaps": 0,
                          **{f"launched_{r}": 0 for r in LAUNCH_REASONS},
-                         "rows_launched": 0, "rows_padded": 0}
+                         "rows_launched": 0, "rows_padded": 0,
+                         "weight_passes": 0}
         self.previous_engine: Optional[XMCEngine] = None  # rollback target
         self.last_swap: Optional[dict] = None   # timing of the latest swap
         self._cv = threading.Condition()
@@ -376,6 +377,9 @@ class XMCServer:
         c[f"launched_{mb.launch}"] += 1
         c["rows_launched"] += rows
         c["rows_padded"] += mb.bucket - rows
+        passes = getattr(engine.backend, "weight_passes", None)
+        if passes is not None:
+            c["weight_passes"] += passes(mb.bucket)
         with TraceAnnotation("xmc.dispatch.handoff"):
             self._inflight.put((mb, scores, labels, t_dispatch))
         return True
@@ -468,7 +472,10 @@ class XMCServer:
         `LATENCY_SAMPLES` requests. `launched_full` / `_deadline` /
         `_forced` count batches by why they launched (they sum to
         `batches`); `rows_launched` counts the requests' rows and
-        `rows_padded` the bucket rows added to them."""
+        `rows_padded` the bucket rows added to them. `weight_passes` sums
+        the passes each launch made over the weights, as a backend with a
+        `weight_passes(bucket)` reports them (the BSR backend does); other
+        backends add nothing."""
         out = dict(self.counters)
         out["pending_requests"] = self.queue.pending_requests()
         accepted = out["accepted"] + out["rejected"]

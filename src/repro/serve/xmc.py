@@ -90,6 +90,7 @@ from repro.compat import resolve_interpret
 from repro.core.prediction import predict_topk_sharded
 from repro.core.pruning import (BlockSparseModel, Int8BlockSparseModel,
                                 quantize_block_sparse, to_block_sparse)
+from repro.kernels.bsr_predict import kernel as bsr_kernel
 from repro.serve.batching import (DEFAULT_BUCKETS, LatencyStats,
                                   MicroBatchQueue)
 from repro.serve.shortlist import ShortlistArtifact, build_shortlist
@@ -370,6 +371,12 @@ class BsrBackend:
         return ("bsr", m.blocks.shape, str(jnp.asarray(m.blocks).dtype),
                 m.shape, m.block_shape, m.orig_shape, self.k, self.n_labels,
                 self._interpret)
+
+    def weight_passes(self, n: int) -> int:
+        """Passes an n-row launch makes over the packed blocks, from the
+        kernel's own layout choice."""
+        m = self.model
+        return bsr_kernel.weight_passes(n, *m.shape, m.block_shape[0])
 
     def topk(self, x: Array) -> tuple[Array, Array]:
         m = self.model

@@ -170,7 +170,8 @@ def _assert_bit_identical_to_per_block(case, n):
         assert model.n_blocks % K.GROUP != 0
     args = (x, model.blocks, model.block_rows, model.block_cols, R)
 
-    got = np.asarray(K.bsr_predict_pallas(*args, interpret=True))
+    got = np.asarray(K.bsr_predict_pallas(*args, row_ptr=model.row_ptr,
+                                          interpret=True))
     per_block = np.asarray(_per_block_pallas(*args))
     live = np.repeat(np.diff(np.asarray(model.row_ptr)) > 0, 16)
     np.testing.assert_array_equal(got[:, live], per_block[:, live])
@@ -208,25 +209,199 @@ def test_row_tiles_are_bit_identical_to_per_block(case, n, monkeypatch):
 
 
 @pytest.mark.parametrize("n,passes,rows", [
-    (1, 1, 1), (64, 1, 64), (128, 1, 128), (208, 1, 208), (216, 2, 112),
+    (1, 1, 1), (64, 1, 64), (128, 1, 128), (208, 1, 208), (216, 1, 216),
     (256, 2, 128)])
 def test_row_tiles_fit_vmem_budget(n, passes, rows):
     """At Wiki10-31K half (Lp 15,488, Dp 102,016) x and the scores fit the
-    VMEM budget up to n = 208 rows; wider launches take equal row tiles
-    within it, one pass over the 16-block groups each. Traced at full
-    size, nothing runs."""
+    VMEM budget up to n = 208 rows; at 216 the scores do not and x does,
+    so the launch streams the scores and holds all its rows; at 256 x does
+    not fit either, and the launch takes equal row tiles within the
+    budget, one pass over the 16-block groups each. Traced at full size,
+    nothing runs."""
     import jax
 
     from repro.kernels.bsr_predict import kernel as K
 
     NB, LP, DP = 96437, 15488, 102016
-    assert K.row_tile(n, LP, DP) == rows
-    assert K._resident_bytes(rows, LP, DP) <= K.VMEM_BUDGET
+    streams = K.streams_scores(n, LP, DP, 128)
+    assert (K._streamed_bytes(rows, DP, 128) if streams
+            else K._resident_bytes(rows, LP, DP)) <= K.VMEM_BUDGET
+    assert streams or K.row_tile(n, LP, DP) == rows
+    assert K.weight_passes(n, LP, DP, 128) == passes
     shapes = [jax.ShapeDtypeStruct(s, d) for s, d in [
         ((n, DP), jnp.float32), ((NB, 128, 128), jnp.float32),
-        ((NB,), jnp.int32), ((NB,), jnp.int32)]]
-    jaxpr = jax.make_jaxpr(lambda x, b, r, c: K.bsr_predict_pallas(
-        x, b, r, c, LP // 128, interpret=False))(*shapes)
+        ((NB,), jnp.int32), ((NB,), jnp.int32),
+        ((LP // 128 + 1,), jnp.int32)]]
+    jaxpr = jax.make_jaxpr(lambda x, b, r, c, p: K.bsr_predict_pallas(
+        x, b, r, c, LP // 128, row_ptr=p, interpret=False))(*shapes)
     (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-    assert call.params["grid_mapping"].grid == (passes, -(-NB // K.GROUP))
+    assert call.params["grid_mapping"].grid == (
+        (-(-LP // 128 // K.STEP_ROW_BLOCKS),) if streams
+        else (passes, -(-NB // K.GROUP)))
     assert jaxpr.out_avals[0].shape == (n, LP)
+
+
+# -- the layout chosen from the shapes: resident, streamed, row tiles ---------
+
+BUCKETS = (1, 2, 4, 8, 16, 32)
+
+
+def _layout_model(case):
+    """16 x 16 blocks over more than 64 label row blocks, so that a score
+    slice pair of the streamed layout is narrower than the scores."""
+    rng = np.random.default_rng(11)
+    if case == "short_rows":          # 6 blocks a row: no group is one row
+        keep = np.ones((70, 6), bool)
+    elif case == "ragged_rows":       # a real pruned checkpoint's rows
+        keep = rng.random((75, 9)) < 0.4
+        keep[[3, 40, 74]] = False     # empty row blocks, the last included
+    elif case == "fully_pruned":      # one sentinel block, in no row
+        keep = np.zeros((70, 6), bool)
+    else:                             # "one_step_partial": 65 row blocks,
+        keep = rng.random((65, 4)) < 0.7      # a last step of one
+    R, C = keep.shape
+    W = rng.normal(size=(R * 16, C * 16)).astype(np.float32)
+    W *= np.kron(keep, np.ones((16, 16), np.float32))
+    return W, to_block_sparse(jnp.asarray(W), (16, 16))
+
+
+def _scores_under(budget, x, model, monkeypatch):
+    from repro.kernels.bsr_predict import kernel as K
+    monkeypatch.setattr(K, "VMEM_BUDGET", budget)
+    out = K.bsr_predict_pallas(x, model.blocks, model.block_rows,
+                               model.block_cols, model.shape[0] // 16,
+                               row_ptr=model.row_ptr, interpret=True)
+    monkeypatch.undo()
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("n", BUCKETS)
+@pytest.mark.parametrize("case", ["short_rows", "ragged_rows",
+                                  "one_step_partial", "fully_pruned"])
+def test_layouts_are_bit_identical(case, n, monkeypatch):
+    """At every bucket of a small engine, the budget is shrunk so that the
+    scores of all n rows do not fit and x does (streamed), and so that
+    neither does (row tiles of 8, n > 8): both return the resident
+    layout's scores bit for bit on live row blocks, exact zeros on empty
+    ones. The streamed walk covers a last partial step of row blocks and
+    a last partial chunk of packed blocks."""
+    from repro.kernels.bsr_predict import kernel as K
+
+    W, model = _layout_model(case)
+    (Lp, Dp), bl = model.shape, 16
+    assert model.n_blocks % K.GROUP != 0
+    assert (Lp // bl) % K.STEP_ROW_BLOCKS != 0
+    x = jnp.asarray(np.random.default_rng(n).normal(size=(n, Dp)),
+                    jnp.float32)
+    assert not K.streams_scores(n, Lp, Dp, bl)
+    resident = _scores_under(K.VMEM_BUDGET, x, model, monkeypatch)
+
+    streamed_budget = K._streamed_bytes(n, Dp, bl)
+    monkeypatch.setattr(K, "VMEM_BUDGET", streamed_budget)
+    assert K.streams_scores(n, Lp, Dp, bl)
+    assert K.weight_passes(n, Lp, Dp, bl) == 1
+    monkeypatch.undo()
+    layouts = {"streamed": _scores_under(streamed_budget, x, model,
+                                         monkeypatch)}
+    if n > 8:
+        tiles_budget = K._resident_bytes(8, Lp, Dp)
+        monkeypatch.setattr(K, "VMEM_BUDGET", tiles_budget)
+        assert not K.streams_scores(n, Lp, Dp, bl)
+        assert K.weight_passes(n, Lp, Dp, bl) == n // 8
+        monkeypatch.undo()
+        layouts["row_tiles"] = _scores_under(tiles_budget, x, model,
+                                             monkeypatch)
+    live = np.repeat(np.diff(np.asarray(model.row_ptr)) > 0, bl)
+    for name, got in layouts.items():
+        np.testing.assert_array_equal(got[:, live], resident[:, live],
+                                      err_msg=name)
+        assert np.all(got[:, ~live] == 0.0), name
+    np.testing.assert_allclose(resident[:, :W.shape[0]], np.asarray(x) @ W.T,
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("n,layout", [(1, "resident"), (16, "resident"),
+                                      (32, "streamed"), (256, "streamed")])
+def test_layout_at_lf_amazontitles_1_3m(n, layout):
+    """At LF-AmazonTitles-1.3M (Lp 1,305,344, Dp 768) the scores of 16 rows
+    fit beside x; wider launches stream them, one weight pass each.
+    Traced at full size, nothing runs."""
+    import jax
+
+    from repro.kernels.bsr_predict import kernel as K
+
+    NRB, NCB = 10198, 6
+    NB, LP, DP = NRB * NCB, NRB * 128, NCB * 128
+    assert K.streams_scores(n, LP, DP, 128) == (layout == "streamed")
+    assert K.weight_passes(n, LP, DP, 128) == 1
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in [
+        ((n, DP), jnp.float32), ((NB, 128, 128), jnp.float32),
+        ((NB,), jnp.int32), ((NB,), jnp.int32), ((NRB + 1,), jnp.int32)]]
+    jaxpr = jax.make_jaxpr(lambda x, b, r, c, p: K.bsr_predict_pallas(
+        x, b, r, c, NRB, row_ptr=p, interpret=False))(*shapes)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    grid = ((-(-NRB // K.STEP_ROW_BLOCKS),) if layout == "streamed"
+            else (1, -(-NB // K.GROUP)))
+    assert call.params["grid_mapping"].grid == grid
+    assert jaxpr.out_avals[0].shape == (n, LP)
+
+
+@pytest.mark.parametrize("n", [4, 8, 16])
+def test_bsr_topk_matches_the_plain_reference(n):
+    """`_bsr_topk` (kernel, masks, top-k) against the plain float32
+    reference at the highest precision (`ref.dense_topk`), on a pruned
+    model with a padded last row block. Scores may differ by one bfloat16
+    pass, each operand rounded to 8 mantissa bits: at most 2^-8 of
+    sum_j |x_j w_lj|. Ids must match at each rank whose reference score is
+    further than both ranks' tolerances from the ranks above and below."""
+    from repro.serve.xmc import BsrBackend
+
+    L, D, k = 1150, 200, 5            # 72 row blocks, the last one padded
+    rng = np.random.default_rng(n)
+    W = np.asarray(prune(jnp.asarray(
+        rng.normal(size=(L, D)).astype(np.float32) * 0.03), 0.01))
+    model = to_block_sparse(jnp.asarray(W), (16, 16))
+    x = rng.normal(size=(n, D)).astype(np.float32)
+    vals, ids = BsrBackend(model, k, n_labels=L).topk(jnp.asarray(x))
+    vals, ids = np.asarray(vals), np.asarray(ids)
+    ref_vals, ref_ids = (np.asarray(a) for a in ref.dense_topk(
+        jnp.asarray(x), jnp.asarray(W), k + 1))
+    scale = np.abs(x) @ np.abs(W).T                       # (n, L)
+    tol = 2.0 ** -8 * np.take_along_axis(scale, ids, 1)
+    served_ref = np.take_along_axis(x.astype(np.float64) @ W.T, ids, 1)
+    assert np.all(np.abs(vals - served_ref) <= tol)
+    # Position j is untied where the reference's j-th score lies more than
+    # both tolerances from its neighbours' (above and below).
+    ref_tol = 2.0 ** -8 * np.take_along_axis(scale, ref_ids, 1)
+    apart = -np.diff(ref_vals, axis=1) > ref_tol[:, :-1] + ref_tol[:, 1:]
+    untied = np.concatenate([np.ones((n, 1), bool), apart[:, :-1]], 1) \
+        & apart
+    assert untied.any()
+    np.testing.assert_array_equal(ids[untied], ref_ids[:, :k][untied])
+
+
+@pytest.mark.parametrize("budget,passes", [("default", 1), ("streamed", 1),
+                                           ("row_tiles", 2)])
+def test_server_counts_weight_passes(budget, passes, monkeypatch):
+    """`XMCServer.stats()["weight_passes"]` sums the passes each launch
+    made over the blocks: one a launch wherever x fits, as many as row
+    tiles otherwise. Buckets 4 and 16: 16 rows take two tiles of 8."""
+    from repro.kernels.bsr_predict import kernel as K
+    from repro.serve import XMCEngine, XMCServer
+    from repro.serve.xmc import BsrBackend
+
+    _, model = _layout_model("short_rows")
+    (Lp, Dp), bl = model.shape, 16
+    if budget == "streamed":
+        monkeypatch.setattr(K, "VMEM_BUDGET", K._streamed_bytes(16, Dp, bl))
+    elif budget == "row_tiles":
+        monkeypatch.setattr(K, "VMEM_BUDGET", K._resident_bytes(8, Lp, Dp))
+    engine = XMCEngine(BsrBackend(model, 3, n_labels=Lp), buckets=(4, 16),
+                       warmup=False, n_features=Dp)
+    server = XMCServer(engine, max_batch_delay_ms=120_000.0, start=False)
+    for rows in (16, 3):              # one full bucket of 16, then 3 -> 4
+        server.submit(np.ones((rows, Dp), np.float32))
+    server.stop()
+    st = server.stats()
+    assert st["batches"] == 2
+    assert st["weight_passes"] == passes + 1

@@ -68,9 +68,10 @@ F32, I32, I8 = jnp.float32, jnp.int32, jnp.int8
 def test_bsr_exhaustive(chip, n, dtype):
     meta = [((NB,), I32), ((NB,), I32)]
     if dtype == "fp32":
-        _compile(chip, lambda x, b, r, c: bsr.bsr_predict_pallas(
-            x, b, r, c, LP // BL, interpret=False),
-            ((n, DP), F32), ((NB, BL, BD), F32), *meta)
+        _compile(chip, lambda x, b, r, c, p: bsr.bsr_predict_pallas(
+            x, b, r, c, LP // BL, row_ptr=p, interpret=False),
+            ((n, DP), F32), ((NB, BL, BD), F32), *meta,
+            ((LP // BL + 1,), I32))
     else:
         _compile(chip, lambda x, b, s, r, c: bsr.bsr_predict_int8_pallas(
             x, b, s, r, c, LP // BL, interpret=False),
@@ -96,9 +97,28 @@ def test_bsr_exhaustive_wiki10(chip, nb, lp, n):
     """The exhaustive fp32 kernel at Wiki10-31K width: the VMEM limit a row
     tile asks for must be granted, and the block coordinates' SMEM windows
     and the 16-block W window must compile however few blocks there are."""
-    _compile(chip, lambda x, b, r, c: bsr.bsr_predict_pallas(
-        x, b, r, c, lp // BL, interpret=False),
-        ((n, DP), F32), ((nb, BL, BD), F32), ((nb,), I32), ((nb,), I32))
+    _compile(chip, lambda x, b, r, c, p: bsr.bsr_predict_pallas(
+        x, b, r, c, lp // BL, row_ptr=p, interpret=False),
+        ((n, DP), F32), ((nb, BL, BD), F32), ((nb,), I32), ((nb,), I32),
+        ((lp // BL + 1,), I32))
+
+
+# LF-AmazonTitles-1.3M: 10,198 x 6 packed blocks, Lp 1,305,344, Dp 768.
+AMAZON_NRB, AMAZON_NCB = 10198, 6
+
+
+@pytest.mark.parametrize("n", [1, 16, 32, 256])
+def test_bsr_exhaustive_lf_amazontitles(chip, n):
+    """The exhaustive fp32 kernel at a million labels over 768 features:
+    up to 16 rows the scores stay resident in VMEM (83.6 MB at 16), wider
+    launches stream them with x resident, and both must compile."""
+    nb = AMAZON_NRB * AMAZON_NCB
+    assert bsr.streams_scores(n, AMAZON_NRB * BL, AMAZON_NCB * BD, BL) == \
+        (n > 16)
+    _compile(chip, lambda x, b, r, c, p: bsr.bsr_predict_pallas(
+        x, b, r, c, AMAZON_NRB, row_ptr=p, interpret=False),
+        ((n, AMAZON_NCB * BD), F32), ((nb, BL, BD), F32), ((nb,), I32),
+        ((nb,), I32), ((AMAZON_NRB + 1,), I32))
 
 
 @pytest.mark.parametrize("n", [1, 16])
