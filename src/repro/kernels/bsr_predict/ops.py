@@ -1,11 +1,26 @@
 """Public wrapper: BSR prediction over a pruned DiSMEC model.
 
 `bsr_predict` yields the dense (n, Lp) score matrix; `bsr_predict_topk`
-reduces it with `jax.lax.top_k` into the serving entry point used by
+reduces it to k candidates per row in the serving entry point used by
 `repro.serve.xmc.BsrBackend` — scores never leave the padded block
-coordinate system before being reduced to k candidates. The masks and the
-top-k run under `jax.named_scope("xmc.topk")`, so a device trace tells
-their time from the kernel's.
+coordinate system before being reduced. The masks and the top-k run under
+`jax.named_scope("xmc.topk")`, so a device trace tells their time from the
+kernel's.
+
+The top-k takes one of two forms, chosen from the shapes (`topk_grouped`):
+`jax.lax.top_k` over the whole row, or, on rows at least
+`GROUPED_TOPK_MIN_LP` wide, the grouped top-k (`grouped_top_k`). The
+latter cuts the row into contiguous groups of `TOPK_GROUP` labels and a
+short tail, reads the scores once in a max reduction over the groups,
+takes the top k of the group maxima, gathers those k groups' scores and
+takes the top k of them and the tail's. It returns `lax.top_k`'s values
+and ids exactly, tie order included: every group whose maximum ranks above
+the k-th group maximum t is selected, so every answer above t is among the
+candidates; an element equal to t in an unselected group is beaten by k
+elements of the selected ones, each above t or equal to t at a lower id,
+since the groups are contiguous and `lax.top_k` prefers the lower index on
+ties. The group maxima are taken over `lax.top_k`'s own order of floats
+(-0.0 below +0.0), as integer keys.
 
 `bsr_predict_gather` / `bsr_predict_gather_topk` are the shortlist-gated
 variants (serve/shortlist.py): given a per-batch list of selected row
@@ -15,6 +30,8 @@ row blocks (sorted) they reproduce the exhaustive path bit-for-bit.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +49,16 @@ from repro.kernels.bsr_predict.kernel import (bsr_predict_gather_int8_pallas,
 # Score of a masked (padding) label: loses to every real score, finite so
 # no -inf reaches a comparison.
 NEG_INF = float(-3.0e38)
+# Labels per group of the grouped top-k: 16 tiles of 128. On a v5e at Lp
+# 1,305,344, groups of 1,024-2,048 labels take a third of the time of groups
+# of 128-256 at 64-256 rows, whose max pass reduces across lanes on every
+# tile; 2,048 is the faster at 256 rows (PERF.md, section 3).
+TOPK_GROUP = 2048
+# Narrowest score row that takes the grouped top-k. On a v5e it wins at
+# Lp 1,305,344 from 16 rows up and loses at Lp 15,488 from 128 rows up, so
+# Wiki10-31K half (Lp 15,488) and EURLex-4K (4,096) stay on one
+# `lax.top_k`.
+GROUPED_TOPK_MIN_LP = 1 << 16
 
 
 def _pad_features(x: jax.Array, model) -> jax.Array:
@@ -55,13 +82,16 @@ def _pad_features(x: jax.Array, model) -> jax.Array:
     return x
 
 
+def _live_labels(model) -> jax.Array:
+    """(Lp,) bool: the label's row block holds a packed block."""
+    counts = model.row_ptr[1:] - model.row_ptr[:-1]          # (Lp/bl,)
+    return jnp.repeat(counts > 0, model.block_shape[0])
+
+
 def _mask_empty_row_blocks(out: jax.Array, model) -> jax.Array:
     # Mask empty row-blocks (undefined memory in the kernel output -- may be
     # NaN in interpret mode, so select rather than multiply).
-    bl = model.block_shape[0]
-    counts = model.row_ptr[1:] - model.row_ptr[:-1]          # (Lp/bl,)
-    row_mask = jnp.repeat(counts > 0, bl)
-    return jnp.where(row_mask[None, :], out, 0.0)
+    return jnp.where(_live_labels(model)[None, :], out, 0.0)
 
 
 def _kernel_scores(x: jax.Array, model: BlockSparseModel,
@@ -113,12 +143,92 @@ def bsr_predict_topk(x: jax.Array, model: BlockSparseModel, k: int,
     """
     scores = _kernel_scores(x, model, interpret)
     with jax.named_scope("xmc.topk"):
-        scores = _mask_empty_row_blocks(scores, model)
         Lp = scores.shape[1]
+        if topk_grouped(Lp, k):
+            real = jnp.arange(Lp) < (Lp if n_labels is None else n_labels)
+            return grouped_top_k(scores, k, TOPK_GROUP,
+                                 _live_labels(model) & real,
+                                 jnp.where(real, 0.0, NEG_INF))
+        scores = _mask_empty_row_blocks(scores, model)
         if n_labels is not None and n_labels < Lp:
             ids = jnp.arange(Lp)
             scores = jnp.where(ids[None, :] < n_labels, scores, NEG_INF)
         return jax.lax.top_k(scores, k)
+
+
+def topk_grouped(Lp: int, k: int) -> bool:
+    """Whether `bsr_predict_topk` takes the grouped top-k over rows of Lp
+    scores: rows at least `GROUPED_TOPK_MIN_LP` wide, of whole 128-label
+    tiles, and k groups' candidates narrower than the row."""
+    return (Lp >= GROUPED_TOPK_MIN_LP and Lp % 128 == 0
+            and k * TOPK_GROUP < Lp)
+
+
+def _order_keys(v: jax.Array) -> jax.Array:
+    """int32 keys that order float32 values as `lax.top_k` does, -0.0
+    below +0.0 (sign-magnitude to two's complement); applied twice, the
+    identity."""
+    i = jax.lax.bitcast_convert_type(v, jnp.int32)
+    return i ^ ((i >> 31) & 0x7FFFFFFF)
+
+
+def grouped_top_k(scores: jax.Array, k: int, c: int, keep: jax.Array,
+                  fill: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """`jax.lax.top_k(jnp.where(keep, scores, fill), k)`, bit for bit, over
+    (n, Lp) scores, Lp a multiple of 128, c one of 128 with k * c < Lp, and
+    `fill` finite (module docstring). `keep` and `fill` are (Lp,).
+
+    The row is cut into G = Lp // c contiguous groups of c labels and a
+    tail of the Lp % c labels after them. One pass over the scores takes
+    each group's maximum, the masks applied as it reads. The top k of
+    those maxima choose k groups, put in ascending order. A gather takes
+    each chosen group's c scores and its masks. The tail's scores join
+    them as candidates outright, after them, so the candidates' ids ascend
+    and the last top k breaks ties as `lax.top_k` does over the row.
+
+    The scores are viewed as (n / r, Lp / 128, r, 128), r = gcd(n, 8): the
+    (r, 128) tiles in which a TPU holds an (n, Lp) float32 array. The max
+    pass reads them in place, across a group's tiles elementwise and
+    across the 128 lanes once per group. A chosen group of a row is c / 128
+    whole tiles of its r-row block, contiguous in memory, so the gather
+    reads them in place too and keeps the row's sublane."""
+    n, Lp = scores.shape
+    nb, s, r = Lp // 128, c // 128, math.gcd(n, 8)
+    G = nb // s
+    tiles = scores.reshape(n // r, r, nb, 128).transpose(0, 2, 1, 3)
+    keys = _order_keys(jnp.where(keep.reshape(nb, 1, 128), tiles,
+                                 fill.reshape(nb, 1, 128)))
+    low = jnp.iinfo(jnp.int32).min
+    gmax = jax.lax.reduce_window(keys, low, jax.lax.max, (1, s, 1, 1),
+                                 (1, s, 1, 1)).max(-1)       # (n/r, G, r)
+    gmax = _order_keys(gmax.transpose(0, 2, 1).reshape(n, G))
+    _, gid = jax.lax.top_k(jax.lax.bitcast_convert_type(gmax, jnp.float32),
+                           k)
+    # The chosen groups in ascending order, by rank.
+    slot = jnp.arange(k)
+    rank = (gid[:, None, :] < gid[:, :, None]).sum(-1)           # (n, k)
+    gid = jnp.where(rank[:, :, None] == slot, gid[:, :, None], 0).sum(1)
+    # Each chosen group's s tiles of the row's r-row block, one slice.
+    row = jnp.arange(n)[:, None]
+    cand = jax.lax.gather(
+        tiles.reshape(n // r * nb, r, 128),
+        (row // r * nb + gid * s)[..., None],
+        jax.lax.GatherDimensionNumbers(offset_dims=(2, 3, 4),
+                                       collapsed_slice_dims=(),
+                                       start_index_map=(0,)),
+        (s, r, 128), mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    mine = jnp.arange(r) == (row % r)[..., None]                  # (n, 1, r)
+    cand = jnp.where(mine[:, :, None, :, None], cand, -jnp.inf).max(3)
+    # Both masks in one (G, c) array, +inf where the score is kept; a
+    # chosen group's row of it is whole tiles, gathered as one slice.
+    mask = jnp.where(keep, jnp.inf, fill)[:G * c].reshape(G, s, 128)
+    mask = mask[gid]                                         # (n, k, s, 128)
+    cand = jnp.where(mask == jnp.inf, cand, mask).reshape(n, k * c)
+    tail = jnp.where(keep[G * c:], scores[:, G * c:], fill[G * c:])
+    vals, j = jax.lax.top_k(jnp.concatenate([cand, tail], axis=1), k)
+    at = jnp.where(j[:, :, None] // c == slot, gid[:, None, :], 0).sum(-1)
+    ids = jnp.where(j < k * c, at * c + j % c, j - k * c + G * c)
+    return vals, ids
 
 
 def bsr_predict_int8_topk(x: jax.Array, model: Int8BlockSparseModel, k: int,

@@ -201,7 +201,7 @@ class XMCServer:
                          "batches": 0, "swaps": 0,
                          **{f"launched_{r}": 0 for r in LAUNCH_REASONS},
                          "rows_launched": 0, "rows_padded": 0,
-                         "weight_passes": 0}
+                         "weight_passes": 0, "topk_grouped": 0}
         self.previous_engine: Optional[XMCEngine] = None  # rollback target
         self.last_swap: Optional[dict] = None   # timing of the latest swap
         self._cv = threading.Condition()
@@ -380,6 +380,9 @@ class XMCServer:
         passes = getattr(engine.backend, "weight_passes", None)
         if passes is not None:
             c["weight_passes"] += passes(mb.bucket)
+        grouped = getattr(engine.backend, "topk_grouped", None)
+        if grouped is not None:
+            c["topk_grouped"] += grouped(mb.bucket)
         with TraceAnnotation("xmc.dispatch.handoff"):
             self._inflight.put((mb, scores, labels, t_dispatch))
         return True
@@ -475,7 +478,9 @@ class XMCServer:
         `rows_padded` the bucket rows added to them. `weight_passes` sums
         the passes each launch made over the weights, as a backend with a
         `weight_passes(bucket)` reports them (the BSR backend does); other
-        backends add nothing."""
+        backends add nothing. `topk_grouped` counts the launches whose
+        top-k took the grouped form, as a backend with a
+        `topk_grouped(bucket)` reports it (the BSR backend does)."""
         out = dict(self.counters)
         out["pending_requests"] = self.queue.pending_requests()
         accepted = out["accepted"] + out["rejected"]

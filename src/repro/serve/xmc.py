@@ -378,6 +378,12 @@ class BsrBackend:
         m = self.model
         return bsr_kernel.weight_passes(n, *m.shape, m.block_shape[0])
 
+    def topk_grouped(self, n: int) -> bool:
+        """Whether an n-row launch's top-k takes the grouped form, from
+        the rule on the score row's width (`ops.topk_grouped`)."""
+        from repro.kernels.bsr_predict import ops as bsr_ops
+        return bsr_ops.topk_grouped(self.model.shape[0], self.k)
+
     def topk(self, x: Array) -> tuple[Array, Array]:
         m = self.model
         return _bsr_topk(x, m.blocks, m.block_rows, m.block_cols, m.row_ptr,

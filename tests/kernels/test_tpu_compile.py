@@ -17,6 +17,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.bsr_predict import kernel as bsr
 from repro.kernels.hinge.kernel import MAX_FUSED_D, hinge_obj_grad_pallas
@@ -119,6 +120,43 @@ def test_bsr_exhaustive_lf_amazontitles(chip, n):
         x, b, r, c, AMAZON_NRB, row_ptr=p, interpret=False),
         ((n, AMAZON_NCB * BD), F32), ((nb, BL, BD), F32), ((nb,), I32),
         ((nb,), I32), ((AMAZON_NRB + 1,), I32))
+
+
+@pytest.mark.parametrize("n", [4, 64, 256])
+def test_bsr_topk_step_lf_amazontitles(chip, n):
+    """The serving step at LF-AmazonTitles-1.3M (`serve/xmc.py::_bsr_topk`,
+    the grouped top-k): the kernel is its only Pallas call, no other
+    instruction writes an array as large as the (n, 1,305,344) scores, so
+    the max pass and the candidates' gather read the kernel's output in
+    place, and no gather is lowered to a loop over its slices."""
+    import functools
+    import re
+
+    from repro.kernels.bsr_predict import ops
+    from repro.serve.xmc import _bsr_topk
+
+    nrb, ncb = AMAZON_NRB, AMAZON_NCB
+    lp, dp, nb = nrb * BL, ncb * BD, nrb * ncb
+    assert ops.topk_grouped(lp, 5)
+    step = functools.partial(
+        _bsr_topk, shape=(lp, dp), block_shape=(BL, BD),
+        orig_shape=(1305265, dp), k=5, n_labels=1305265, interpret=False)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in [
+        ((n, dp), F32), ((nb, BL, BD), F32), ((nb,), I32), ((nb,), I32),
+        ((nrb + 1,), I32)]]
+    text = jax.jit(step).lower(*args).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 1
+    assert " while(" not in entry
+    wide = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(", line)
+        if m and m.group(2) not in ("parameter", "custom-call", "bitcast"):
+            size = np.prod([int(d) for d in m.group(1).split(",") if d])
+            if size >= n * lp:
+                wide.append(line.strip()[:120])
+    assert not wide
 
 
 @pytest.mark.parametrize("n", [1, 16])
