@@ -62,6 +62,10 @@ class BlockSparseModel:
       shape      : (Lp, Dp) block-padded shape of the packed matrix
       orig_shape : (L, D) true pre-padding shape — the labels/features that
                    actually exist; serving must never answer outside it
+
+    A pytree: the arrays are its leaves and the three shapes its static
+    part, so a jitted step takes the model whole and compiles once per
+    geometry.
     """
     blocks: Array
     block_rows: Array
@@ -124,6 +128,14 @@ class BlockSparseModel:
         return load_block_sparse(directory)
 
 
+#: How both packed model classes split into pytree leaves and static
+#: fields (`jax.tree_util.register_dataclass`, below each class).
+_MODEL_ARRAYS = ("blocks", "block_rows", "block_cols", "row_ptr")
+_MODEL_SHAPES = ("shape", "block_shape", "orig_shape")
+jax.tree_util.register_dataclass(BlockSparseModel, data_fields=_MODEL_ARRAYS,
+                                 meta_fields=_MODEL_SHAPES)
+
+
 #: Symmetric int8 range: scale = max|block| / INT8_QMAX, values in
 #: [-INT8_QMAX, INT8_QMAX]. -128 is never produced, so negation round-trips.
 INT8_QMAX = 127
@@ -164,7 +176,8 @@ class Int8BlockSparseModel:
     with it. Coordinates (`block_rows` / `block_cols` / `row_ptr`) and
     shapes are shared with the fp32 `BlockSparseModel` it was quantized
     from; the int8 Pallas kernels dequantize in-register against the
-    per-block scale and accumulate in fp32.
+    per-block scale and accumulate in fp32. A pytree like the fp32 model,
+    with `scales` one more leaf.
     """
     blocks: Array                     # (n_blocks, bl, bd) int8
     scales: Array                     # (n_blocks,) float32
@@ -201,6 +214,12 @@ class Int8BlockSparseModel:
             block_rows=self.block_rows, block_cols=self.block_cols,
             row_ptr=self.row_ptr, shape=self.shape,
             block_shape=self.block_shape, orig_shape=self.orig_shape)
+
+
+jax.tree_util.register_dataclass(Int8BlockSparseModel,
+                                 data_fields=("blocks", "scales",
+                                              *_MODEL_ARRAYS[1:]),
+                                 meta_fields=_MODEL_SHAPES)
 
 
 def quantize_block_sparse(model: "BlockSparseModel",

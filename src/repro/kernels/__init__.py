@@ -11,8 +11,12 @@ Kernels (DESIGN.md §3):
               solver protocol, core/tron.py)
   hvp         fused generalized-Hessian vector product consuming the
               cached mask (CG inner loop)
-  bsr_predict block-sparse W x predict — skips Delta-pruned zero blocks;
-              its top-k is `jax.lax.top_k` over the (n, L) scores
+  bsr_predict block-sparse W x predict — skips Delta-pruned zero blocks,
+              over fp32 or int8 blocks; `bsr_predict_topk` reduces the
+              (n, Lp) scores in one of two exact forms: `jax.lax.top_k`
+              over the row, or on rows of 65,536 labels or more the
+              grouped top-k (a max pass over groups of labels, then a
+              top-k over the best groups' candidates)
 
 Every kernel takes `interpret=None` and auto-selects per backend
 (compiled Mosaic on TPU, the interpreter elsewhere —

@@ -427,7 +427,7 @@ def bsr_predict_int8_pallas(x: jax.Array, blocks: jax.Array,
     )(block_rows, block_cols, scales, x, blocks)
 
 
-def _bsr_gather_kernel(sel_ref, rptr_ref, cols_ref, x_ref, blk_ref, o_ref):
+def _bsr_gather_kernel(sel_ref, rptr_ref, cols_ref, *refs):
     """Grid step (i, j): j-th packed block of selected row block sel[i].
 
     o[:, i-th tile] += x[:, cols[ptr]] @ blocks[ptr]^T  for
@@ -436,8 +436,14 @@ def _bsr_gather_kernel(sel_ref, rptr_ref, cols_ref, x_ref, blk_ref, o_ref):
     The output tile is zero-initialized at j == 0 unconditionally, so a
     selected row block with NO surviving blocks yields exact-zero scores —
     the same pruned-label convention as the exhaustive path.
+
+    refs is (x, blocks, o), or (scales, x, blocks, o) for int8 blocks: the
+    in-bounds ptr then also reads the block's scale, which multiplies its
+    fp32 dot, as in the exhaustive int8 kernel (same bits at full
+    coverage).
     """
     del cols_ref
+    *scales_ref, x_ref, blk_ref, o_ref = refs
     i, j = pl.program_id(0), pl.program_id(1)
     r = sel_ref[i]
 
@@ -447,15 +453,19 @@ def _bsr_gather_kernel(sel_ref, rptr_ref, cols_ref, x_ref, blk_ref, o_ref):
 
     @pl.when(rptr_ref[r] + j < rptr_ref[r + 1])
     def _acc():
-        o_ref[...] += jax.lax.dot_general(
+        d = jax.lax.dot_general(
             x_ref[...].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        if scales_ref:
+            d = scales_ref[0][rptr_ref[r] + j] * d
+        o_ref[...] += d
 
 
 def bsr_predict_gather_pallas(x: jax.Array, blocks: jax.Array,
                               block_cols: jax.Array, row_ptr: jax.Array,
                               sel: jax.Array, max_blocks_per_row: int,
-                              *, interpret: bool | None = None) -> jax.Array:
+                              *, scales: jax.Array | None = None,
+                              interpret: bool | None = None) -> jax.Array:
     """Gathered-block BSR predict: score only the row blocks listed in `sel`.
 
     x (n, Dp), blocks (nb, bl, bd) row-major packed, row_ptr (R + 1,),
@@ -463,6 +473,8 @@ def bsr_predict_gather_pallas(x: jax.Array, blocks: jax.Array,
     (n, B * bl), where columns [i*bl, (i+1)*bl) are the scores of row block
     sel[i]'s labels. `max_blocks_per_row` bounds the inner grid dimension
     (static: max(row_ptr[r+1] - row_ptr[r]) over all row blocks, >= 1).
+    Int8 blocks come with their (nb,) fp32 `scales`, scalar-prefetched
+    beside the coordinates; fp32 blocks come without.
 
     Both BlockSpec index maps clamp the packed pointer to nb - 1 so padding
     grid steps (j beyond a short row's block count) fetch a valid tile; the
@@ -472,110 +484,45 @@ def bsr_predict_gather_pallas(x: jax.Array, blocks: jax.Array,
     n = x.shape[0]
     nb, bl, bd = blocks.shape
     B = sel.shape[0]
+    prefetch = (sel, row_ptr, block_cols) + (() if scales is None
+                                             else (scales,))
 
-    def _ptr(i, j, sel_a, rptr_a, cols_a):
+    def _ptr(i, j, sel_a, rptr_a, *_):
         return jnp.minimum(rptr_a[sel_a[i]] + j, nb - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, max_blocks_per_row),
         in_specs=[
-            pl.BlockSpec((n, bd),
-                         lambda i, j, sel_a, rptr_a, cols_a:
-                         (0, cols_a[_ptr(i, j, sel_a, rptr_a, cols_a)])),
-            pl.BlockSpec((1, bl, bd),
-                         lambda i, j, sel_a, rptr_a, cols_a:
-                         (_ptr(i, j, sel_a, rptr_a, cols_a), 0, 0)),
+            pl.BlockSpec((n, bd), lambda i, j, sel_a, rptr_a, cols_a, *_:
+                         (0, cols_a[_ptr(i, j, sel_a, rptr_a)])),
+            pl.BlockSpec((1, bl, bd), lambda i, j, *refs:
+                         (_ptr(i, j, *refs), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((n, bl),
-                               lambda i, j, sel_a, rptr_a, cols_a: (0, i)),
+        out_specs=pl.BlockSpec((n, bl), lambda i, j, *_: (0, i)),
     )
     return pl.pallas_call(
         _bsr_gather_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, B * bl), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(sel, row_ptr, block_cols, x, blocks)
+    )(*prefetch, x, blocks)
 
 
-def _bsr_gather_int8_kernel(sel_ref, rptr_ref, cols_ref, scales_ref,
-                            x_ref, blk_ref, o_ref):
-    """Int8 variant of `_bsr_gather_kernel`: same clamp/gate structure,
-    with the clamped packed pointer also indexing the per-block scale and
-    the scale applied to the fp32 partial product — the same in-register
-    dequantization as the exhaustive int8 kernel, so full coverage is
-    bit-for-bit identical."""
-    del cols_ref
-    i, j = pl.program_id(0), pl.program_id(1)
-    r = sel_ref[i]
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(rptr_ref[r] + j < rptr_ref[r + 1])
-    def _acc():
-        ptr = rptr_ref[r] + j            # in-bounds inside the gate
-        o_ref[...] += scales_ref[ptr] * jax.lax.dot_general(
-            x_ref[...].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-
-def bsr_predict_gather_int8_pallas(x: jax.Array, blocks: jax.Array,
-                                   scales: jax.Array, block_cols: jax.Array,
-                                   row_ptr: jax.Array, sel: jax.Array,
-                                   max_blocks_per_row: int, *,
-                                   interpret: bool | None = None,
-                                   ) -> jax.Array:
-    """Gathered-block int8 predict: the shortlist fine stage over int8
-    tiles. Same contract as `bsr_predict_gather_pallas` with (blocks int8,
-    scales fp32) replacing the fp32 blocks; padding grid steps fetch a
-    clamped tile and add nothing, and the scale is read only inside the
-    in-bounds gate."""
-    n = x.shape[0]
-    nb, bl, bd = blocks.shape
-    B = sel.shape[0]
-
-    def _ptr(i, j, sel_a, rptr_a, cols_a, scales_a):
-        return jnp.minimum(rptr_a[sel_a[i]] + j, nb - 1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, max_blocks_per_row),
-        in_specs=[
-            pl.BlockSpec((n, bd),
-                         lambda i, j, sel_a, rptr_a, cols_a, scales_a:
-                         (0, cols_a[_ptr(i, j, sel_a, rptr_a, cols_a,
-                                         scales_a)])),
-            pl.BlockSpec((1, bl, bd),
-                         lambda i, j, sel_a, rptr_a, cols_a, scales_a:
-                         (_ptr(i, j, sel_a, rptr_a, cols_a, scales_a),
-                          0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (n, bl),
-            lambda i, j, sel_a, rptr_a, cols_a, scales_a: (0, i)),
-    )
-    return pl.pallas_call(
-        _bsr_gather_int8_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, B * bl), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(sel, row_ptr, block_cols, scales, x, blocks)
-
-
-def _bsr_gather_pq_kernel(sel_ref, rptr_ref, cols_ref, x_ref, blk_ref, o_ref):
+def _bsr_gather_pq_kernel(sel_ref, rptr_ref, cols_ref, *refs):
     """Ragged per-query gather, grid step (q, i, j): j-th packed block of
     row block sel[q, i] — query q's OWN i-th selected block, scored against
     query q's single row.
 
     o[q-th row, i-th tile] += x[q, cols[ptr]] @ blocks[ptr]^T  for
-    ptr = row_ptr[sel[q, i]] + j, gated on j < blocks-in-row exactly like
-    the shared-selection kernel; the (1, bl) output tile is zero-initialized
-    at j == 0. Each query walks its own block list, so a query whose
-    selection hits sparse row blocks does strictly less accumulation work
-    than one that hit dense rows — the shared-B union's worst-case cost is
-    gone.
+    ptr = row_ptr[sel[q, i]] + j, gated and scaled exactly like the
+    shared-selection kernel (refs likewise); the (1, bl) output tile is
+    zero-initialized at j == 0. Each query walks its own block list, so a
+    query whose selection hits sparse row blocks does strictly less
+    accumulation work than one that hit dense rows — the shared-B union's
+    worst-case cost is gone.
     """
     del cols_ref
+    *scales_ref, x_ref, blk_ref, o_ref = refs
     q, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     r = sel_ref[q, i]
 
@@ -585,15 +532,19 @@ def _bsr_gather_pq_kernel(sel_ref, rptr_ref, cols_ref, x_ref, blk_ref, o_ref):
 
     @pl.when(rptr_ref[r] + j < rptr_ref[r + 1])
     def _acc():
-        o_ref[0] += jax.lax.dot_general(
+        d = jax.lax.dot_general(
             x_ref[0].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        if scales_ref:
+            d = scales_ref[0][rptr_ref[r] + j] * d
+        o_ref[0] += d
 
 
 def bsr_predict_gather_pq_pallas(x: jax.Array, blocks: jax.Array,
                                  block_cols: jax.Array, row_ptr: jax.Array,
                                  sel: jax.Array, max_blocks_per_row: int,
-                                 *, interpret: bool | None = None,
+                                 *, scales: jax.Array | None = None,
+                                 interpret: bool | None = None,
                                  ) -> jax.Array:
     """Per-query gathered-block BSR predict: row q scores only ITS row
     blocks `sel[q]`.
@@ -603,6 +554,7 @@ def bsr_predict_gather_pq_pallas(x: jax.Array, blocks: jax.Array,
     duplicates) -> scores (n, B * bl), where row q's columns
     [i*bl, (i+1)*bl) are the scores of row block sel[q, i]'s labels (a
     per-row ragged layout; ops.py owns the per-row label translation).
+    `scales` as in `bsr_predict_gather_pallas`.
 
     The grid is (n, B, max_blocks_per_row) with j innermost, so each
     (1, bl) output tile stays resident across its row block's packed
@@ -624,92 +576,27 @@ def bsr_predict_gather_pq_pallas(x: jax.Array, blocks: jax.Array,
     n = x.shape[0]
     nb, bl, bd = blocks.shape
     B = sel.shape[1]
+    prefetch = (sel, row_ptr, block_cols) + (() if scales is None
+                                             else (scales,))
 
-    def _ptr(q, i, j, sel_a, rptr_a, cols_a):
+    def _ptr(q, i, j, sel_a, rptr_a, *_):
         return jnp.minimum(rptr_a[sel_a[q, i]] + j, nb - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(n, B, max_blocks_per_row),
         in_specs=[
             pl.BlockSpec((1, 1, bd),
-                         lambda q, i, j, sel_a, rptr_a, cols_a:
-                         (q, 0, cols_a[_ptr(q, i, j, sel_a, rptr_a,
-                                            cols_a)])),
-            pl.BlockSpec((1, bl, bd),
-                         lambda q, i, j, sel_a, rptr_a, cols_a:
-                         (_ptr(q, i, j, sel_a, rptr_a, cols_a), 0, 0)),
+                         lambda q, i, j, sel_a, rptr_a, cols_a, *_:
+                         (q, 0, cols_a[_ptr(q, i, j, sel_a, rptr_a)])),
+            pl.BlockSpec((1, bl, bd), lambda q, i, j, *refs:
+                         (_ptr(q, i, j, *refs), 0, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, bl), lambda q, i, j, sel_a, rptr_a, cols_a: (q, 0, i)),
+        out_specs=pl.BlockSpec((1, 1, bl), lambda q, i, j, *_: (q, 0, i)),
     )
     out = pl.pallas_call(
         _bsr_gather_pq_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, B * bl), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(sel, row_ptr, block_cols, x[:, None, :], blocks)
-    return out[:, 0, :]
-
-
-def _bsr_gather_pq_int8_kernel(sel_ref, rptr_ref, cols_ref, scales_ref,
-                               x_ref, blk_ref, o_ref):
-    """Int8 variant of `_bsr_gather_pq_kernel`: identical clamp/gate
-    structure, with the in-bounds packed pointer indexing the per-block
-    scale and the scale applied to the fp32 partial product — the same
-    in-register dequantization as every other int8 kernel in this file."""
-    del cols_ref
-    q, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    r = sel_ref[q, i]
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(rptr_ref[r] + j < rptr_ref[r + 1])
-    def _acc():
-        ptr = rptr_ref[r] + j            # in-bounds inside the gate
-        o_ref[0] += scales_ref[ptr] * jax.lax.dot_general(
-            x_ref[0].astype(jnp.float32), blk_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-
-def bsr_predict_gather_pq_int8_pallas(x: jax.Array, blocks: jax.Array,
-                                      scales: jax.Array,
-                                      block_cols: jax.Array,
-                                      row_ptr: jax.Array, sel: jax.Array,
-                                      max_blocks_per_row: int, *,
-                                      interpret: bool | None = None,
-                                      ) -> jax.Array:
-    """Per-query gathered-block int8 predict: same contract as
-    `bsr_predict_gather_pq_pallas` with (blocks int8, scales fp32)
-    replacing the fp32 blocks."""
-    n = x.shape[0]
-    nb, bl, bd = blocks.shape
-    B = sel.shape[1]
-
-    def _ptr(q, i, j, sel_a, rptr_a, cols_a, scales_a):
-        return jnp.minimum(rptr_a[sel_a[q, i]] + j, nb - 1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n, B, max_blocks_per_row),
-        in_specs=[
-            pl.BlockSpec((1, 1, bd),
-                         lambda q, i, j, sel_a, rptr_a, cols_a, scales_a:
-                         (q, 0, cols_a[_ptr(q, i, j, sel_a, rptr_a, cols_a,
-                                            scales_a)])),
-            pl.BlockSpec((1, bl, bd),
-                         lambda q, i, j, sel_a, rptr_a, cols_a, scales_a:
-                         (_ptr(q, i, j, sel_a, rptr_a, cols_a, scales_a),
-                          0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, bl),
-            lambda q, i, j, sel_a, rptr_a, cols_a, scales_a: (q, 0, i)),
-    )
-    out = pl.pallas_call(
-        _bsr_gather_pq_int8_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, 1, B * bl), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(sel, row_ptr, block_cols, scales, x[:, None, :], blocks)
+    )(*prefetch, x[:, None, :], blocks)
     return out[:, 0, :]

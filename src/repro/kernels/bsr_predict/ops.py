@@ -1,11 +1,16 @@
 """Public wrapper: BSR prediction over a pruned DiSMEC model.
 
+Every wrapper takes either packed model: a float32 `BlockSparseModel` or
+the int8 `Int8BlockSparseModel`, whose per-block scales multiply each
+block's fp32 dot. The kernel follows from the model's type, a fact of the
+trace, so one jitted step compiles a separate program per dtype.
+
 `bsr_predict` yields the dense (n, Lp) score matrix; `bsr_predict_topk`
 reduces it to k candidates per row in the serving entry point used by
-`repro.serve.xmc.BsrBackend` — scores never leave the padded block
-coordinate system before being reduced. The masks and the top-k run under
-`jax.named_scope("xmc.topk")`, so a device trace tells their time from the
-kernel's.
+`repro.serve.xmc.BsrBackend` and `Int8Backend` — scores never leave the
+padded block coordinate system before being reduced. The masks and the
+top-k run under `jax.named_scope("xmc.topk")`, so a device trace tells
+their time from the kernel's.
 
 The top-k takes one of two forms, chosen from the shapes (`topk_grouped`):
 `jax.lax.top_k` over the whole row, or, on rows at least
@@ -26,7 +31,9 @@ ties. The group maxima are taken over `lax.top_k`'s own order of floats
 variants (serve/shortlist.py): given a per-batch list of selected row
 blocks they score ONLY those blocks' packed tiles, so per-query compute
 scales with B * block_size instead of L. With the selection covering all
-row blocks (sorted) they reproduce the exhaustive path bit-for-bit.
+row blocks (sorted) they reproduce the exhaustive path bit-for-bit, in
+either dtype. `bsr_predict_gather_pq` / `bsr_predict_gather_pq_topk` take
+one selection per query row.
 """
 
 from __future__ import annotations
@@ -38,13 +45,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.pruning import BlockSparseModel, Int8BlockSparseModel
-from repro.kernels.bsr_predict.kernel import (bsr_predict_gather_int8_pallas,
-                                              bsr_predict_gather_pallas,
-                                              bsr_predict_gather_pq_int8_pallas,
+from repro.kernels.bsr_predict.kernel import (bsr_predict_gather_pallas,
                                               bsr_predict_gather_pq_pallas,
                                               bsr_predict_int8_pallas,
                                               bsr_predict_pallas,
                                               weight_passes)
+
+#: Either packed model: every wrapper below takes both, and picks the
+#: kernel from the type, which is known when the wrapper is traced.
+Model = BlockSparseModel | Int8BlockSparseModel
 
 # Score of a masked (padding) label: loses to every real score, finite so
 # no -inf reaches a comparison.
@@ -94,44 +103,37 @@ def _mask_empty_row_blocks(out: jax.Array, model) -> jax.Array:
     return jnp.where(_live_labels(model)[None, :], out, 0.0)
 
 
-def _kernel_scores(x: jax.Array, model: BlockSparseModel,
+def _kernel_scores(x: jax.Array, model: Model,
                    interpret: bool | None) -> jax.Array:
     """The kernel's (n, Lp) scores, x padded to the model's width; row
-    blocks with no surviving block are not yet masked."""
-    Lp, Dp = model.shape
-    bl, bd = model.block_shape
-    return bsr_predict_pallas(_pad_features(x, model), model.blocks,
+    blocks with no surviving block are not yet masked. Int8 blocks go
+    through the int8 kernel, which applies each block's scale to its fp32
+    dot."""
+    Lp, bl = model.shape[0], model.block_shape[0]
+    x = _pad_features(x, model)
+    if isinstance(model, Int8BlockSparseModel):
+        return bsr_predict_int8_pallas(x, model.blocks, model.scales,
+                                       model.block_rows, model.block_cols,
+                                       Lp // bl, interpret=interpret)
+    return bsr_predict_pallas(x, model.blocks,
                               model.block_rows, model.block_cols, Lp // bl,
                               row_ptr=model.row_ptr, interpret=interpret)
 
 
-def bsr_predict(x: jax.Array, model: BlockSparseModel,
+def bsr_predict(x: jax.Array, model: Model,
                 *, interpret: bool | None = None) -> jax.Array:
     """Scores (n, L) for a batch against a block-sparse model.
 
     Pads x's feature dim to the padded model shape (raising when the
     request is WIDER than the model) and zeroes out label row-blocks that
-    have no surviving blocks (never visited by the kernel).
+    have no surviving blocks (never visited by the kernel). Int8 scores
+    match the fp32 path within the per-block quantization bound
+    (|w - scale*q| <= scale/2 elementwise), at ~0.25x the weight traffic.
     """
     return _mask_empty_row_blocks(_kernel_scores(x, model, interpret), model)
 
 
-def bsr_predict_int8(x: jax.Array, model: Int8BlockSparseModel,
-                     *, interpret: bool | None = None) -> jax.Array:
-    """Scores (n, L) against the int8 per-block-scaled artifact — same
-    pad/mask conventions as `bsr_predict`, ~0.25x the model HBM traffic.
-    Scores match the fp32 path within the per-block quantization bound
-    (|w - scale*q| <= scale/2 elementwise)."""
-    Lp, Dp = model.shape
-    bl, bd = model.block_shape
-    x = _pad_features(x, model)
-    out = bsr_predict_int8_pallas(x, model.blocks, model.scales,
-                                  model.block_rows, model.block_cols,
-                                  Lp // bl, interpret=interpret)
-    return _mask_empty_row_blocks(out, model)
-
-
-def bsr_predict_topk(x: jax.Array, model: BlockSparseModel, k: int,
+def bsr_predict_topk(x: jax.Array, model: Model, k: int,
                      *, n_labels: int | None = None,
                      interpret: bool | None = None,
                      ) -> tuple[jax.Array, jax.Array]:
@@ -139,7 +141,8 @@ def bsr_predict_topk(x: jax.Array, model: BlockSparseModel, k: int,
 
     Padding label rows (id >= n_labels) are masked to NEG_INF before the
     top-k so a block-padded model never serves phantom labels. Fully
-    pruned real labels keep their exact-zero score, matching the dense path.
+    pruned real labels keep their exact-zero score, matching the dense path
+    (an all-zero int8 block quantizes to scale 0).
     """
     scores = _kernel_scores(x, model, interpret)
     with jax.named_scope("xmc.topk"):
@@ -231,23 +234,6 @@ def grouped_top_k(scores: jax.Array, k: int, c: int, keep: jax.Array,
     return vals, ids
 
 
-def bsr_predict_int8_topk(x: jax.Array, model: Int8BlockSparseModel, k: int,
-                          *, n_labels: int | None = None,
-                          interpret: bool | None = None,
-                          ) -> tuple[jax.Array, jax.Array]:
-    """Fused int8 predict -> top-k: (vals, idx) each (n, k), idx in true
-    label ids — the `"int8"` backend's serving entry point. Padding labels
-    are masked to NEG_INF before the top-k and fully pruned real labels
-    keep their exact-zero score (an all-zero block quantizes to scale 0),
-    matching the fp32 conventions."""
-    scores = bsr_predict_int8(x, model, interpret=interpret)
-    Lp = scores.shape[1]
-    if n_labels is not None and n_labels < Lp:
-        ids = jnp.arange(Lp)
-        scores = jnp.where(ids[None, :] < n_labels, scores, NEG_INF)
-    return jax.lax.top_k(scores, k)
-
-
 def max_blocks_per_row(model: BlockSparseModel) -> int:
     """Static bound on packed blocks per row block (>= 1) — the inner grid
     extent of the gathered-block kernel."""
@@ -255,7 +241,13 @@ def max_blocks_per_row(model: BlockSparseModel) -> int:
     return max(1, int(np.max(ptr[1:] - ptr[:-1])))
 
 
-def bsr_predict_gather(x: jax.Array, model: BlockSparseModel,
+def _scales(model: Model) -> jax.Array | None:
+    """The per-block scales the gather kernels take for int8 blocks, None
+    for fp32 ones."""
+    return model.scales if isinstance(model, Int8BlockSparseModel) else None
+
+
+def bsr_predict_gather(x: jax.Array, model: Model,
                        sel: jax.Array, *,
                        max_per_row: int | None = None,
                        interpret: bool | None = None) -> jax.Array:
@@ -265,33 +257,21 @@ def bsr_predict_gather(x: jax.Array, model: BlockSparseModel,
     label scores. Pads x's feature dim like `bsr_predict`; a selected row
     block with no surviving blocks comes back exact-zero (the kernel
     zero-initializes every selected output tile), so pruned labels keep
-    the dense path's score convention without any extra masking.
+    the dense path's score convention without any extra masking. At
+    sel = every row block, sorted, the scores equal `bsr_predict`'s bit for
+    bit, for int8 blocks too: the scale multiplies the same per-block fp32
+    dot in the same order.
     """
     x = _pad_features(x, model)
     if max_per_row is None:
         max_per_row = max_blocks_per_row(model)
     return bsr_predict_gather_pallas(
         x, model.blocks, model.block_cols, model.row_ptr,
-        jnp.asarray(sel, jnp.int32), max_per_row, interpret=interpret)
+        jnp.asarray(sel, jnp.int32), max_per_row, scales=_scales(model),
+        interpret=interpret)
 
 
-def bsr_predict_gather_int8(x: jax.Array, model: Int8BlockSparseModel,
-                            sel: jax.Array, *,
-                            max_per_row: int | None = None,
-                            interpret: bool | None = None) -> jax.Array:
-    """Int8 scores for ONLY the row blocks listed in `sel` (B,) int32 —
-    the shortlist fine stage over the quantized artifact. Same contract
-    as `bsr_predict_gather` (exact-zero empty blocks included: their
-    packed sentinel quantizes to zeros)."""
-    x = _pad_features(x, model)
-    if max_per_row is None:
-        max_per_row = max_blocks_per_row(model)
-    return bsr_predict_gather_int8_pallas(
-        x, model.blocks, model.scales, model.block_cols, model.row_ptr,
-        jnp.asarray(sel, jnp.int32), max_per_row, interpret=interpret)
-
-
-def bsr_predict_gather_topk(x: jax.Array, model: BlockSparseModel,
+def bsr_predict_gather_topk(x: jax.Array, model: Model,
                             sel: jax.Array, k: int, *,
                             n_labels: int | None = None,
                             max_per_row: int | None = None,
@@ -319,29 +299,7 @@ def bsr_predict_gather_topk(x: jax.Array, model: BlockSparseModel,
     return vals, jnp.take(label_ids, idx)
 
 
-def bsr_predict_gather_int8_topk(x: jax.Array, model: Int8BlockSparseModel,
-                                 sel: jax.Array, k: int, *,
-                                 n_labels: int | None = None,
-                                 max_per_row: int | None = None,
-                                 interpret: bool | None = None,
-                                 ) -> tuple[jax.Array, jax.Array]:
-    """Fused gathered int8 predict -> top-k: the shortlist backend's fine
-    stage over the quantized artifact. Same contract as
-    `bsr_predict_gather_topk` (idx in true label ids, padding masked, sorted
-    full-coverage `sel` reproduces `bsr_predict_int8_topk` bit-for-bit —
-    the scale multiplies the same per-block fp32 dot in the same order)."""
-    bl = model.block_shape[0]
-    sel = jnp.asarray(sel, jnp.int32)
-    scores = bsr_predict_gather_int8(x, model, sel, max_per_row=max_per_row,
-                                     interpret=interpret)
-    label_ids = (sel[:, None] * bl + jnp.arange(bl)[None, :]).reshape(-1)
-    if n_labels is not None:
-        scores = jnp.where(label_ids[None, :] < n_labels, scores, NEG_INF)
-    vals, idx = jax.lax.top_k(scores, k)
-    return vals, jnp.take(label_ids, idx)
-
-
-def bsr_predict_gather_pq(x: jax.Array, model: BlockSparseModel,
+def bsr_predict_gather_pq(x: jax.Array, model: Model,
                           sel: jax.Array, *,
                           max_per_row: int | None = None,
                           interpret: bool | None = None) -> jax.Array:
@@ -357,39 +315,11 @@ def bsr_predict_gather_pq(x: jax.Array, model: BlockSparseModel,
         max_per_row = max_blocks_per_row(model)
     return bsr_predict_gather_pq_pallas(
         x, model.blocks, model.block_cols, model.row_ptr,
-        jnp.asarray(sel, jnp.int32), max_per_row, interpret=interpret)
+        jnp.asarray(sel, jnp.int32), max_per_row, scales=_scales(model),
+        interpret=interpret)
 
 
-def bsr_predict_gather_pq_int8(x: jax.Array, model: Int8BlockSparseModel,
-                               sel: jax.Array, *,
-                               max_per_row: int | None = None,
-                               interpret: bool | None = None) -> jax.Array:
-    """Per-query gathered int8 scores — `bsr_predict_gather_pq` over the
-    quantized artifact."""
-    x = _pad_features(x, model)
-    if max_per_row is None:
-        max_per_row = max_blocks_per_row(model)
-    return bsr_predict_gather_pq_int8_pallas(
-        x, model.blocks, model.scales, model.block_cols, model.row_ptr,
-        jnp.asarray(sel, jnp.int32), max_per_row, interpret=interpret)
-
-
-def _pq_translate_topk(scores: jax.Array, sel: jax.Array, bl: int, k: int,
-                       n_labels: int | None,
-                       ) -> tuple[jax.Array, jax.Array]:
-    """Shared tail of the per-query topk wrappers: mask block padding per
-    row and translate merged top-k back to true label ids via each row's
-    own candidate list."""
-    # (n, B*bl): row q's candidate column c is label sel[q, c//bl]*bl + c%bl.
-    label_ids = (sel[:, :, None] * bl
-                 + jnp.arange(bl)[None, None, :]).reshape(sel.shape[0], -1)
-    if n_labels is not None:
-        scores = jnp.where(label_ids < n_labels, scores, NEG_INF)
-    vals, idx = jax.lax.top_k(scores, k)
-    return vals, jnp.take_along_axis(label_ids, idx, axis=1)
-
-
-def bsr_predict_gather_pq_topk(x: jax.Array, model: BlockSparseModel,
+def bsr_predict_gather_pq_topk(x: jax.Array, model: Model,
                                sel: jax.Array, k: int, *,
                                n_labels: int | None = None,
                                max_per_row: int | None = None,
@@ -403,24 +333,13 @@ def bsr_predict_gather_pq_topk(x: jax.Array, model: BlockSparseModel,
     sel = jnp.asarray(sel, jnp.int32)
     scores = bsr_predict_gather_pq(x, model, sel, max_per_row=max_per_row,
                                    interpret=interpret)
-    return _pq_translate_topk(scores, sel, bl, k, n_labels)
-
-
-def bsr_predict_gather_pq_int8_topk(x: jax.Array,
-                                    model: Int8BlockSparseModel,
-                                    sel: jax.Array, k: int, *,
-                                    n_labels: int | None = None,
-                                    max_per_row: int | None = None,
-                                    interpret: bool | None = None,
-                                    ) -> tuple[jax.Array, jax.Array]:
-    """Fused per-query gathered int8 predict -> top-k: same contract as
-    `bsr_predict_gather_pq_topk` over the quantized artifact."""
-    bl = model.block_shape[0]
-    sel = jnp.asarray(sel, jnp.int32)
-    scores = bsr_predict_gather_pq_int8(x, model, sel,
-                                        max_per_row=max_per_row,
-                                        interpret=interpret)
-    return _pq_translate_topk(scores, sel, bl, k, n_labels)
+    # (n, B*bl): row q's candidate column c is label sel[q, c//bl]*bl + c%bl.
+    label_ids = (sel[:, :, None] * bl
+                 + jnp.arange(bl)[None, None, :]).reshape(sel.shape[0], -1)
+    if n_labels is not None:
+        scores = jnp.where(label_ids < n_labels, scores, NEG_INF)
+    vals, idx = jax.lax.top_k(scores, k)
+    return vals, jnp.take_along_axis(label_ids, idx, axis=1)
 
 
 def gather_flops(model: BlockSparseModel, n: int, sel: np.ndarray) -> int:

@@ -15,12 +15,12 @@ differently). Backends live in a decorator registry —
 multi-model, ...) into the engine, `make_backend` is a thin lookup, and
 `ServeSpec(backend="kind")` selects it without touching engine code.
 
-One engine, four built-in interchangeable backends behind the
+One engine, five built-in interchangeable backends behind the
 `PredictBackend` protocol:
 
   dense     — jitted X @ W.T + lax.top_k on the densified model. Baseline
               and reference semantics.
-  bsr       — the block-sparse Pallas predict kernel, then lax.top_k
+  bsr       — the block-sparse Pallas predict kernel, then the top-k
               (kernels/bsr_predict.ops.bsr_predict_topk); the
               model stays in packed BSR form end-to-end, compute scales
               with block density.
@@ -35,8 +35,8 @@ One engine, four built-in interchangeable backends behind the
               (bsr_predict_gather_topk) scores only those blocks. Compute
               scales with B * block_size + R * D, not L * D. Falls back to
               exhaustive BSR when the checkpoint has no shortlist artifact.
-              `ShortlistBackend(int8=True)` swaps the fine stage to the
-              int8 gathered kernel — coarse gate AND quarter weight traffic.
+              `ShortlistBackend(int8=True)` gives the fine stage the int8
+              model — coarse gate AND quarter weight traffic.
               `per_query=True` selects top-B blocks per QUERY and scores
               each row's own list through the ragged-gather kernel
               (bsr_predict_gather_pq_topk); B = n_row_blocks collapses back
@@ -62,7 +62,8 @@ percentiles (enqueue -> completion, so queue wait is measured). The
 synchronous path is `submit()` + `step()`; `engine.server()` wraps the
 same engine in the async continuous-batching loop (`serve/server.py`) —
 future-style results, deadline-launched buckets, admission control —
-without changing the backend math or the top-k bits. Backend math lives in module-level jitted functions, so two
+without changing the backend math or the top-k bits. Backend math lives
+in module-level jitted functions that take the packed model whole, so two
 backends over equal-shaped models share one XLA compile cache entry per
 bucket — opening a second engine never repeats the first one's warm-up
 compiles (the process-wide ledger below skips the redundant dispatches).
@@ -114,12 +115,11 @@ class PredictBackend(Protocol):
 
 
 # ---------------------------------------------------------------------------
-# Module-level jitted scoring functions. Backends used to close jit over
-# per-instance state, so every backend object carried its own compile cache
-# and a second engine over an equal-shaped model re-paid every bucket
-# compile. At module level jax keys the cache on (arg shapes/dtypes, static
-# values) alone: any two backends with equal (D, k) and model geometry share
-# one executable per bucket.
+# Module-level jitted scoring functions. A packed model is a pytree
+# (core/pruning.py) and goes in whole: jax keys the compile cache on its
+# arrays' shapes and dtypes and its static shapes, so any two backends with
+# equal (D, k) and model geometry share one executable per bucket, and an
+# fp32 model and its int8 twin never do.
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -127,211 +127,83 @@ def _dense_topk(x: Array, W: Array, k: int) -> tuple[Array, Array]:
     return jax.lax.top_k(x @ W.T, k)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "interpret"))
-def _bsr_topk(x, blocks, block_rows, block_cols, row_ptr, *, shape,
-              block_shape, orig_shape, k, n_labels, interpret):
+@functools.partial(jax.jit, static_argnames=("k", "n_labels", "interpret"))
+def _bsr_topk(x, model, *, k, n_labels, interpret):
+    """Exhaustive fine stage over either packed model: the kernel of its
+    dtype, the masks and the top-k (`bsr_predict_topk`)."""
     from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    model = BlockSparseModel(blocks=blocks, block_rows=block_rows,
-                             block_cols=block_cols, row_ptr=row_ptr,
-                             shape=shape, block_shape=block_shape,
-                             orig_shape=orig_shape)
     return bsr_ops.bsr_predict_topk(x, model, k, n_labels=n_labels,
                                     interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("B",))
-def _shortlist_select(x: Array, centroids: Array, B: int) -> Array:
-    """Coarse stage: top-B row blocks for one micro-batch, sorted ascending.
-
-    One (n, Dp) x (Dp, R) matmul, max over the batch's per-query scores
-    (static output shape: one selection serves the whole micro-batch), then
-    lax.top_k. The sort makes B = R reproduce exhaustive scoring bit-for-bit
-    (same float accumulation order into the same top-k input).
-    """
-    Dp = centroids.shape[1]
-    xf = x.astype(jnp.float32)
-    if xf.shape[1] < Dp:
-        xf = jnp.pad(xf, ((0, 0), (0, Dp - xf.shape[1])))
-    coarse = xf @ centroids.T                      # (n, R)
+def _top_blocks(coarse: Array, B: int, per_query: bool) -> Array:
+    """Top-B row blocks from (n, R) coarse scores, sorted ascending: one
+    (B,) list for the micro-batch from the max over its rows (static
+    output shape), or with `per_query` an (n, B) list for each row. The
+    sort makes B = R reproduce exhaustive scoring bit-for-bit (same float
+    accumulation order into the same top-k input)."""
+    if per_query:
+        _, sel = jax.lax.top_k(coarse, B)
+        return jnp.sort(sel, axis=1)
     _, sel = jax.lax.top_k(coarse.max(axis=0), B)
     return jnp.sort(sel)
 
 
-@functools.partial(jax.jit, static_argnames=("B",))
-def _shortlist_select_pq(x: Array, centroids: Array, B: int) -> Array:
-    """Per-query coarse stage: top-B row blocks for EACH row of the
-    micro-batch, each row's list sorted ascending. The ragged-gather fine
-    stage scores row q against exactly its own list — easy queries stop
-    paying for the batch union's width. Only reached for B < n_row_blocks
-    (full width collapses to `_shortlist_select`, see ShortlistBackend)."""
-    Dp = centroids.shape[1]
+def _pad_query(x: Array, Dp: int) -> Array:
+    """x as float32, zero-padded to the coarse stage's width Dp."""
     xf = x.astype(jnp.float32)
     if xf.shape[1] < Dp:
         xf = jnp.pad(xf, ((0, 0), (0, Dp - xf.shape[1])))
-    coarse = xf @ centroids.T                      # (n, R)
-    _, sel = jax.lax.top_k(coarse, B)              # (n, B) per-row
-    return jnp.sort(sel, axis=1)
+    return xf
 
 
-@functools.partial(jax.jit, static_argnames=("depth",))
-def _tree_coarse(x: Array, nodes: Array, leaf_scores: Array,
-                 depth: int) -> Array:
-    """Tree-routing coarse scores: descend the complete binary tree of
+@functools.partial(jax.jit, static_argnames=("B", "per_query"))
+def _shortlist_select(x: Array, centroids: Array, *, B: int,
+                      per_query: bool) -> Array:
+    """Matrix coarse stage: one (n, Dp) x (Dp, R) matmul, then the top-B
+    row blocks (`_top_blocks`)."""
+    coarse = _pad_query(x, centroids.shape[1]) @ centroids.T     # (n, R)
+    return _top_blocks(coarse, B, per_query)
+
+
+@functools.partial(jax.jit, static_argnames=("depth", "B", "per_query"))
+def _tree_select(x: Array, nodes: Array, leaf_scores: Array, *, depth: int,
+                 B: int, per_query: bool) -> Array:
+    """Tree-routing coarse stage: descend the complete binary tree of
     hyperplanes (level-order `nodes`, one (Dp,) normal each) for `depth`
-    static steps, then read the reached leaf's per-row-block score row.
-    Returns (n, R) — fed to the same shared/per-query block selection as
-    the matrix coarse kinds."""
-    Dp = nodes.shape[1]
-    xf = x.astype(jnp.float32)
-    if xf.shape[1] < Dp:
-        xf = jnp.pad(xf, ((0, 0), (0, Dp - xf.shape[1])))
+    static steps, read the reached leaf's (R,) per-row-block score row,
+    then take the top-B row blocks as the matrix kinds do."""
+    xf = _pad_query(x, nodes.shape[1])
     idx = jnp.zeros((xf.shape[0],), jnp.int32)
     for _ in range(depth):                         # static, tiny depth
         w = nodes[idx]                             # (n, Dp) routed normals
         go_right = (jnp.sum(xf * w, axis=1) >= 0.0).astype(jnp.int32)
         idx = 2 * idx + 1 + go_right
     leaf = idx - (2 ** depth - 1)
-    return leaf_scores[leaf]                       # (n, R)
-
-
-@functools.partial(jax.jit, static_argnames=("B",))
-def _select_shared_from(coarse: Array, B: int) -> Array:
-    """Shared top-B selection from precomputed (n, R) coarse scores."""
-    _, sel = jax.lax.top_k(coarse.max(axis=0), B)
-    return jnp.sort(sel)
-
-
-@functools.partial(jax.jit, static_argnames=("B",))
-def _select_pq_from(coarse: Array, B: int) -> Array:
-    """Per-query top-B selection from precomputed (n, R) coarse scores."""
-    _, sel = jax.lax.top_k(coarse, B)
-    return jnp.sort(sel, axis=1)
+    return _top_blocks(leaf_scores[leaf], B, per_query)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "max_per_row",
-    "interpret"))
-def _gather_topk(x, sel, blocks, block_rows, block_cols, row_ptr, *, shape,
-                 block_shape, orig_shape, k, n_labels, max_per_row,
-                 interpret):
-    """Shared-selection fine stage with the (B,) selection as a runtime
-    argument (the tree coarse stage computes it outside this trace)."""
+    "k", "n_labels", "max_per_row", "interpret"))
+def _gather_topk(x, sel, model, *, k, n_labels, max_per_row, interpret):
+    """Gathered fine stage over either packed model, the selection a
+    runtime argument: (B,) shared by the micro-batch, or (n, B), one list
+    per row, as `sel.ndim` says."""
     from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    model = BlockSparseModel(blocks=blocks, block_rows=block_rows,
-                             block_cols=block_cols, row_ptr=row_ptr,
-                             shape=shape, block_shape=block_shape,
-                             orig_shape=orig_shape)
-    return bsr_ops.bsr_predict_gather_topk(x, model, sel, k,
-                                           n_labels=n_labels,
-                                           max_per_row=max_per_row,
-                                           interpret=interpret)
+    fn = (bsr_ops.bsr_predict_gather_pq_topk if sel.ndim == 2
+          else bsr_ops.bsr_predict_gather_topk)
+    return fn(x, model, sel, k, n_labels=n_labels, max_per_row=max_per_row,
+              interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "max_per_row",
-    "interpret"))
-def _gather_int8_topk(x, sel, blocks, scales, block_rows, block_cols,
-                      row_ptr, *, shape, block_shape, orig_shape, k,
-                      n_labels, max_per_row, interpret):
-    from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    model = Int8BlockSparseModel(blocks=blocks, scales=scales,
-                                 block_rows=block_rows, block_cols=block_cols,
-                                 row_ptr=row_ptr, shape=shape,
-                                 block_shape=block_shape,
-                                 orig_shape=orig_shape)
-    return bsr_ops.bsr_predict_gather_int8_topk(x, model, sel, k,
-                                                n_labels=n_labels,
-                                                max_per_row=max_per_row,
-                                                interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "max_per_row",
-    "interpret"))
-def _gather_pq_topk(x, sel, blocks, block_rows, block_cols, row_ptr, *,
-                    shape, block_shape, orig_shape, k, n_labels,
+    "k", "n_labels", "B", "per_query", "max_per_row", "interpret"))
+def _shortlist_topk(x, centroids, model, *, k, n_labels, B, per_query,
                     max_per_row, interpret):
-    """Per-query ragged fine stage: sel is (n, B), row q scores only its
-    own block list through the prefetch-steered ragged-gather kernel."""
-    from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    model = BlockSparseModel(blocks=blocks, block_rows=block_rows,
-                             block_cols=block_cols, row_ptr=row_ptr,
-                             shape=shape, block_shape=block_shape,
-                             orig_shape=orig_shape)
-    return bsr_ops.bsr_predict_gather_pq_topk(x, model, sel, k,
-                                              n_labels=n_labels,
-                                              max_per_row=max_per_row,
-                                              interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "max_per_row",
-    "interpret"))
-def _gather_pq_int8_topk(x, sel, blocks, scales, block_rows, block_cols,
-                         row_ptr, *, shape, block_shape, orig_shape, k,
-                         n_labels, max_per_row, interpret):
-    from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    model = Int8BlockSparseModel(blocks=blocks, scales=scales,
-                                 block_rows=block_rows, block_cols=block_cols,
-                                 row_ptr=row_ptr, shape=shape,
-                                 block_shape=block_shape,
-                                 orig_shape=orig_shape)
-    return bsr_ops.bsr_predict_gather_pq_int8_topk(x, model, sel, k,
-                                                   n_labels=n_labels,
-                                                   max_per_row=max_per_row,
-                                                   interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "B",
-    "max_per_row", "interpret"))
-def _shortlist_topk(x, centroids, blocks, block_rows, block_cols, row_ptr,
-                    *, shape, block_shape, orig_shape, k, n_labels, B,
-                    max_per_row, interpret):
-    from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    sel = _shortlist_select(x, centroids, B)
-    model = BlockSparseModel(blocks=blocks, block_rows=block_rows,
-                             block_cols=block_cols, row_ptr=row_ptr,
-                             shape=shape, block_shape=block_shape,
-                             orig_shape=orig_shape)
-    return bsr_ops.bsr_predict_gather_topk(x, model, sel, k,
-                                           n_labels=n_labels,
-                                           max_per_row=max_per_row,
-                                           interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "interpret"))
-def _bsr_int8_topk(x, blocks, scales, block_rows, block_cols, row_ptr, *,
-                   shape, block_shape, orig_shape, k, n_labels, interpret):
-    from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    model = Int8BlockSparseModel(blocks=blocks, scales=scales,
-                                 block_rows=block_rows, block_cols=block_cols,
-                                 row_ptr=row_ptr, shape=shape,
-                                 block_shape=block_shape,
-                                 orig_shape=orig_shape)
-    return bsr_ops.bsr_predict_int8_topk(x, model, k, n_labels=n_labels,
-                                         interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "shape", "block_shape", "orig_shape", "k", "n_labels", "B",
-    "max_per_row", "interpret"))
-def _shortlist_int8_topk(x, centroids, blocks, scales, block_rows,
-                         block_cols, row_ptr, *, shape, block_shape,
-                         orig_shape, k, n_labels, B, max_per_row, interpret):
-    from repro.kernels.bsr_predict import ops as bsr_ops   # deferred: no cycle
-    sel = _shortlist_select(x, centroids, B)
-    model = Int8BlockSparseModel(blocks=blocks, scales=scales,
-                                 block_rows=block_rows, block_cols=block_cols,
-                                 row_ptr=row_ptr, shape=shape,
-                                 block_shape=block_shape,
-                                 orig_shape=orig_shape)
-    return bsr_ops.bsr_predict_gather_int8_topk(x, model, sel, k,
-                                                n_labels=n_labels,
-                                                max_per_row=max_per_row,
-                                                interpret=interpret)
+    """Matrix coarse stage and fine stage in one dispatch."""
+    sel = _shortlist_select(x, centroids, B=B, per_query=per_query)
+    return _gather_topk(x, sel, model, k=k, n_labels=n_labels,
+                        max_per_row=max_per_row, interpret=interpret)
 
 
 class DenseBackend:
@@ -352,13 +224,11 @@ class DenseBackend:
         return self._fn(x)
 
 
-class BsrBackend:
-    """Packed block-sparse model through the Pallas predict kernel + top-k."""
+class _PackedBackend:
+    """Exhaustive scoring of a packed model through `_bsr_topk`; a
+    subclass names its kind in `name`."""
 
-    name = "bsr"
-
-    def __init__(self, model: BlockSparseModel, k: int,
-                 *, n_labels: int | None = None,
+    def __init__(self, model, k: int, *, n_labels: int | None = None,
                  interpret: bool | None = None):
         self.k = k
         self.n_labels = int(n_labels if n_labels is not None
@@ -367,10 +237,23 @@ class BsrBackend:
         self._interpret = resolve_interpret(interpret)
 
     def warmup_key(self):
+        # Leads with the kind tag AND the blocks' dtype: an int8 backend
+        # over the same geometry as a fp32 bsr backend must never mark the
+        # fp32 bucket warm (different executable, different numerics).
         m = self.model
-        return ("bsr", m.blocks.shape, str(jnp.asarray(m.blocks).dtype),
+        return (self.name, m.blocks.shape, str(jnp.asarray(m.blocks).dtype),
                 m.shape, m.block_shape, m.orig_shape, self.k, self.n_labels,
                 self._interpret)
+
+    def topk(self, x: Array) -> tuple[Array, Array]:
+        return _bsr_topk(x, self.model, k=self.k, n_labels=self.n_labels,
+                         interpret=self._interpret)
+
+
+class BsrBackend(_PackedBackend):
+    """Packed block-sparse model through the Pallas predict kernel + top-k."""
+
+    name = "bsr"
 
     def weight_passes(self, n: int) -> int:
         """Passes an n-row launch makes over the packed blocks, from the
@@ -384,20 +267,14 @@ class BsrBackend:
         from repro.kernels.bsr_predict import ops as bsr_ops
         return bsr_ops.topk_grouped(self.model.shape[0], self.k)
 
-    def topk(self, x: Array) -> tuple[Array, Array]:
-        m = self.model
-        return _bsr_topk(x, m.blocks, m.block_rows, m.block_cols, m.row_ptr,
-                         shape=m.shape, block_shape=m.block_shape,
-                         orig_shape=m.orig_shape, k=self.k,
-                         n_labels=self.n_labels, interpret=self._interpret)
 
-
-class Int8Backend:
+class Int8Backend(_PackedBackend):
     """Exhaustive BSR scoring over the int8 per-block-scaled artifact.
 
     Accepts either the quantized artifact directly or a fp32
     `BlockSparseModel` (quantized here — identical bytes to the persisted
     checkpoint artifact, so legacy fp32-only checkpoints serve int8 too).
+    The server counts no weight passes or grouped top-ks for it.
     """
 
     name = "int8"
@@ -406,29 +283,7 @@ class Int8Backend:
                  interpret: bool | None = None):
         if isinstance(model, BlockSparseModel):
             model = quantize_block_sparse(model)
-        self.k = k
-        self.n_labels = int(n_labels if n_labels is not None
-                            else model.n_labels)
-        self.model = model
-        self._interpret = resolve_interpret(interpret)
-
-    def warmup_key(self):
-        # Leads with a distinct kind tag AND the int8 dtype: an int8 backend
-        # over the same geometry as a fp32 bsr backend must never mark the
-        # fp32 bucket warm (different executable, different numerics).
-        m = self.model
-        return ("int8", m.blocks.shape, str(jnp.asarray(m.blocks).dtype),
-                m.shape, m.block_shape, m.orig_shape, self.k, self.n_labels,
-                self._interpret)
-
-    def topk(self, x: Array) -> tuple[Array, Array]:
-        m = self.model
-        return _bsr_int8_topk(x, m.blocks, m.scales, m.block_rows,
-                              m.block_cols, m.row_ptr, shape=m.shape,
-                              block_shape=m.block_shape,
-                              orig_shape=m.orig_shape, k=self.k,
-                              n_labels=self.n_labels,
-                              interpret=self._interpret)
+        super().__init__(model, k, n_labels=n_labels, interpret=interpret)
 
 
 class ShortlistBackend:
@@ -519,13 +374,10 @@ class ShortlistBackend:
         per-query, row-sorted either way."""
         if self.kind == "tree":
             nodes, leaf_scores, depth = self._tree
-            coarse = _tree_coarse(x, nodes, leaf_scores, depth)
-            if self.per_query:
-                return _select_pq_from(coarse, self.B)
-            return _select_shared_from(coarse, self.B)
-        if self.per_query:
-            return _shortlist_select_pq(x, self._centroids, self.B)
-        return _shortlist_select(x, self._centroids, self.B)
+            return _tree_select(x, nodes, leaf_scores, depth=depth, B=self.B,
+                                per_query=self.per_query)
+        return _shortlist_select(x, self._centroids, B=self.B,
+                                 per_query=self.per_query)
 
     def select_blocks(self, x: Array) -> np.ndarray:
         """Coarse-stage introspection: the sorted row-block ids the fine
@@ -534,40 +386,13 @@ class ShortlistBackend:
         return np.asarray(self._select(jnp.asarray(x, jnp.float32)))
 
     def topk(self, x: Array) -> tuple[Array, Array]:
-        if self.kind != "tree" and not self.per_query:
-            # Matrix coarse + shared selection: the original fused paths,
-            # byte-for-byte untouched (the B == R bit-exactness contract
-            # and all pre-v2 serving behavior ride on these).
-            if self.int8:
-                q = self.int8_model
-                return _shortlist_int8_topk(
-                    x, self._centroids, q.blocks, q.scales, q.block_rows,
-                    q.block_cols, q.row_ptr, shape=q.shape,
-                    block_shape=q.block_shape, orig_shape=q.orig_shape,
-                    k=self.k, n_labels=self.n_labels, B=self.B,
-                    max_per_row=self._max_per_row, interpret=self._interpret)
-            m = self.model
-            return _shortlist_topk(
-                x, self._centroids, m.blocks, m.block_rows, m.block_cols,
-                m.row_ptr, shape=m.shape, block_shape=m.block_shape,
-                orig_shape=m.orig_shape, k=self.k, n_labels=self.n_labels,
-                B=self.B, max_per_row=self._max_per_row,
-                interpret=self._interpret)
-        sel = self._select(x)
-        if self.int8:
-            q = self.int8_model
-            fn = _gather_pq_int8_topk if self.per_query else _gather_int8_topk
-            return fn(x, sel, q.blocks, q.scales, q.block_rows, q.block_cols,
-                      q.row_ptr, shape=q.shape, block_shape=q.block_shape,
-                      orig_shape=q.orig_shape, k=self.k,
-                      n_labels=self.n_labels, max_per_row=self._max_per_row,
-                      interpret=self._interpret)
-        m = self.model
-        fn = _gather_pq_topk if self.per_query else _gather_topk
-        return fn(x, sel, m.blocks, m.block_rows, m.block_cols, m.row_ptr,
-                  shape=m.shape, block_shape=m.block_shape,
-                  orig_shape=m.orig_shape, k=self.k, n_labels=self.n_labels,
+        fine = self.int8_model if self.int8 else self.model
+        kw = dict(k=self.k, n_labels=self.n_labels,
                   max_per_row=self._max_per_row, interpret=self._interpret)
+        if self.kind == "tree":
+            return _gather_topk(x, self._select(x), fine, **kw)
+        return _shortlist_topk(x, self._centroids, fine, B=self.B,
+                               per_query=self.per_query, **kw)
 
 
 class RelabelBackend:

@@ -81,10 +81,9 @@ def test_bsr_predict_topk_masks_block_padding(dtype, n, k):
     if dtype == "int8":
         model = quantize_block_sparse(model)
         dense = np.asarray(model.dequantize().to_dense())[:L, :D]
-        vals, ids = ops.bsr_predict_int8_topk(x, model, k, n_labels=L)
     else:
         dense = W
-        vals, ids = ops.bsr_predict_topk(x, model, k, n_labels=L)
+    vals, ids = ops.bsr_predict_topk(x, model, k, n_labels=L)
     v_ref, i_ref = jax.lax.top_k(jnp.asarray(np.asarray(x) @ dense.T), k)
     assert np.all(np.asarray(ids) < L)
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(i_ref))

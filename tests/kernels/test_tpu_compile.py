@@ -132,19 +132,23 @@ def test_bsr_topk_step_lf_amazontitles(chip, n):
     import functools
     import re
 
+    from repro.core.pruning import BlockSparseModel
     from repro.kernels.bsr_predict import ops
     from repro.serve.xmc import _bsr_topk
 
     nrb, ncb = AMAZON_NRB, AMAZON_NCB
     lp, dp, nb = nrb * BL, ncb * BD, nrb * ncb
     assert ops.topk_grouped(lp, 5)
-    step = functools.partial(
-        _bsr_topk, shape=(lp, dp), block_shape=(BL, BD),
-        orig_shape=(1305265, dp), k=5, n_labels=1305265, interpret=False)
-    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in [
-        ((n, dp), F32), ((nb, BL, BD), F32), ((nb,), I32), ((nb,), I32),
-        ((nrb + 1,), I32)]]
-    text = jax.jit(step).lower(*args).compile().as_text()
+    step = functools.partial(_bsr_topk, k=5, n_labels=1305265,
+                             interpret=False)
+    x, blocks, rows, cols, ptr = [
+        jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in [
+            ((n, dp), F32), ((nb, BL, BD), F32), ((nb,), I32), ((nb,), I32),
+            ((nrb + 1,), I32)]]
+    model = BlockSparseModel(blocks=blocks, block_rows=rows, block_cols=cols,
+                             row_ptr=ptr, shape=(lp, dp),
+                             block_shape=(BL, BD), orig_shape=(1305265, dp))
+    text = jax.jit(step).lower(x, model).compile().as_text()
     entry = text[text.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
     assert entry.count('custom_call_target="tpu_custom_call"') == 1
@@ -159,35 +163,31 @@ def test_bsr_topk_step_lf_amazontitles(chip, n):
     assert not wide
 
 
+def _gather_args(n, dtype, sel):
+    """Shapes of (x, blocks, block_cols, row_ptr, sel, scales) at Wiki10-31K
+    width; no scales for fp32 blocks."""
+    blocks = ((NB, BL, BD), F32 if dtype == "fp32" else I8)
+    scales = [] if dtype == "fp32" else [((NB,), F32)]
+    return [((n, DP), F32), blocks, ((NB,), I32), ((LP // BL + 1,), I32),
+            (sel, I32), *scales]
+
+
 @pytest.mark.parametrize("n", [1, 16])
 @pytest.mark.parametrize("dtype", ["fp32", "int8"])
 def test_bsr_gather(chip, n, dtype):
-    tail = [((NB,), I32), ((LP // BL + 1,), I32), ((SEL,), I32)]
-    if dtype == "fp32":
-        _compile(chip, lambda x, b, c, p, s: bsr.bsr_predict_gather_pallas(
-            x, b, c, p, s, MAX_PER_ROW, interpret=False),
-            ((n, DP), F32), ((NB, BL, BD), F32), *tail)
-    else:
-        _compile(chip, lambda x, b, sc, c, p, s:
-                 bsr.bsr_predict_gather_int8_pallas(
-                     x, b, sc, c, p, s, MAX_PER_ROW, interpret=False),
-                 ((n, DP), F32), ((NB, BL, BD), I8), ((NB,), F32), *tail)
+    _compile(chip, lambda x, b, c, p, s, sc=None:
+             bsr.bsr_predict_gather_pallas(
+                 x, b, c, p, s, MAX_PER_ROW, scales=sc, interpret=False),
+             *_gather_args(n, dtype, (SEL,)))
 
 
 @pytest.mark.parametrize("n", [1, 16])
 @pytest.mark.parametrize("dtype", ["fp32", "int8"])
 def test_bsr_gather_pq(chip, n, dtype):
-    tail = [((NB,), I32), ((LP // BL + 1,), I32), ((n, SEL), I32)]
-    if dtype == "fp32":
-        _compile(chip, lambda x, b, c, p, s:
-                 bsr.bsr_predict_gather_pq_pallas(
-                     x, b, c, p, s, MAX_PER_ROW, interpret=False),
-                 ((n, DP), F32), ((NB, BL, BD), F32), *tail)
-    else:
-        _compile(chip, lambda x, b, sc, c, p, s:
-                 bsr.bsr_predict_gather_pq_int8_pallas(
-                     x, b, sc, c, p, s, MAX_PER_ROW, interpret=False),
-                 ((n, DP), F32), ((NB, BL, BD), I8), ((NB,), F32), *tail)
+    _compile(chip, lambda x, b, c, p, s, sc=None:
+             bsr.bsr_predict_gather_pq_pallas(
+                 x, b, c, p, s, MAX_PER_ROW, scales=sc, interpret=False),
+             *_gather_args(n, dtype, (n, SEL)))
 
 
 def test_hinge_at_max_fused_d(chip):

@@ -2,7 +2,7 @@
 parity (exhaustive + gathered) against the dequantize oracle, fp32/int8
 top-k agreement across block densities, checkpoint persistence vs lazy
 quantization (single-shard + stream), the ServeSpec knob, the D > Dp
-guard on all four predict wrappers, and warm-up ledger isolation."""
+guard on every predict wrapper, and warm-up ledger isolation."""
 
 import os
 
@@ -83,51 +83,63 @@ def test_model_quantize_method_matches_function():
 # Int8 kernels vs oracle; full-coverage gather is bit-exact
 # ---------------------------------------------------------------------------
 
-def test_int8_kernel_matches_oracle():
-    """Pallas int8 exhaustive scoring == dequantize-then-fp32 oracle, on a
-    non-tile-aligned shape (row + feature padding both engaged)."""
+_ORACLE = {"fp32": (bsr_ref.bsr_predict, bsr_ref.bsr_predict_gather),
+           "int8": (bsr_ref.bsr_predict_int8, bsr_ref.bsr_predict_gather_int8)}
+
+
+def _packed(W, dtype):
+    """W packed at (16, 128) blocks, quantized for "int8"."""
+    model = to_block_sparse(jnp.asarray(W), (16, 128))
+    return model.quantize() if dtype == "int8" else model
+
+
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+def test_int8_kernel_matches_oracle(dtype):
+    """Pallas exhaustive scoring (`bsr_predict`, either dtype) ==
+    dequantize-then-fp32 oracle, on a non-tile-aligned shape (row +
+    feature padding both engaged)."""
     L, D = 100, 300
     W = _block_sparse_W(L, D, 0.6, seed=11)
-    q = to_block_sparse(jnp.asarray(W), (16, 128)).quantize()
+    q = _packed(W, dtype)
     x = jnp.asarray(np.random.default_rng(1).normal(size=(4, D)), jnp.float32)
-    got = bsr_ops.bsr_predict_int8(x, q)
-    want = bsr_ref.bsr_predict_int8(
-        jnp.pad(x, ((0, 0), (0, q.shape[1] - D))), q)
+    got = bsr_ops.bsr_predict(x, q)
+    want = _ORACLE[dtype][0](jnp.pad(x, ((0, 0), (0, q.shape[1] - D))), q)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_int8_gather_kernel_matches_oracle_unsorted_sel():
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+def test_int8_gather_kernel_matches_oracle_unsorted_sel(dtype):
     L, D = 100, 300
     W = _block_sparse_W(L, D, 0.6, seed=12)
-    q = to_block_sparse(jnp.asarray(W), (16, 128)).quantize()
+    q = _packed(W, dtype)
     x = jnp.asarray(np.random.default_rng(2).normal(size=(3, D)), jnp.float32)
     sel = jnp.asarray([5, 0, 3], jnp.int32)
-    got = bsr_ops.bsr_predict_gather_int8(x, q, sel)
-    want = bsr_ref.bsr_predict_gather_int8(
-        jnp.pad(x, ((0, 0), (0, q.shape[1] - D))), q, sel)
+    got = bsr_ops.bsr_predict_gather(x, q, sel)
+    want = _ORACLE[dtype][1](jnp.pad(x, ((0, 0), (0, q.shape[1] - D))), q,
+                             sel)
     assert got.shape == (3, 3 * 16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_full_coverage_gather_int8_bitwise_exhaustive():
-    """sel = every row block: the gathered-int8 kernel must reproduce the
-    exhaustive int8 kernel BIT-FOR-BIT — both dequantize against the same
-    per-block scale in the same fp32 accumulation order, so composing the
-    shortlist gate with int8 adds no numerics of its own."""
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+def test_full_coverage_gather_int8_bitwise_exhaustive(dtype):
+    """sel = every row block: the gathered kernel must reproduce the
+    exhaustive kernel BIT-FOR-BIT — for int8 both dequantize against the
+    same per-block scale in the same fp32 accumulation order, so composing
+    the shortlist gate with int8 adds no numerics of its own."""
     L, D = 128, 256
     W = _block_sparse_W(L, D, 0.5, seed=13)
-    q = to_block_sparse(jnp.asarray(W), (16, 128)).quantize()
+    q = _packed(W, dtype)
     n_row_blocks = q.shape[0] // q.block_shape[0]
     x = jnp.asarray(np.random.default_rng(3).normal(size=(4, D)), jnp.float32)
     sel = jnp.arange(n_row_blocks, dtype=jnp.int32)
-    full = bsr_ops.bsr_predict_gather_int8(x, q, sel)
-    exhaustive = bsr_ops.bsr_predict_int8(x, q)
+    full = bsr_ops.bsr_predict_gather(x, q, sel)
+    exhaustive = bsr_ops.bsr_predict(x, q)
     assert np.array_equal(np.asarray(full), np.asarray(exhaustive))
-    sc_g, lb_g = bsr_ops.bsr_predict_gather_int8_topk(x, q, sel, 5,
-                                                      n_labels=L)
-    sc_e, lb_e = bsr_ops.bsr_predict_int8_topk(x, q, 5, n_labels=L)
+    sc_g, lb_g = bsr_ops.bsr_predict_gather_topk(x, q, sel, 5, n_labels=L)
+    sc_e, lb_e = bsr_ops.bsr_predict_topk(x, q, 5, n_labels=L)
     assert np.array_equal(np.asarray(sc_g), np.asarray(sc_e))
     assert np.array_equal(np.asarray(lb_g), np.asarray(lb_e))
 
@@ -176,24 +188,28 @@ def test_int8_topk_agreement_across_densities(density):
 
 def test_oversized_request_raises_on_all_wrappers():
     """A request wider than the model's padded feature dim must fail with
-    a ValueError naming both dims — on the fp32 AND int8, exhaustive AND
-    gathered wrappers — not shape-err deep inside the kernel."""
+    a ValueError naming both dims — on every predict wrapper, exhaustive,
+    gathered and per-query, scores and top-k, over the fp32 AND the int8
+    model — not shape-err deep inside the kernel."""
     L, D = 64, 256
     W = _block_sparse_W(L, D, 0.5, seed=21)
     bsr = to_block_sparse(jnp.asarray(W), (16, 128))
-    q = bsr.quantize()
     Dp = bsr.shape[1]
     x_wide = jnp.ones((2, Dp + 64), jnp.float32)
     sel = jnp.asarray([0], jnp.int32)
+    sel_pq = jnp.zeros((2, 1), jnp.int32)
     pattern = rf"feature dim {Dp + 64}.*{Dp}"
-    with pytest.raises(ValueError, match=pattern):
-        bsr_ops.bsr_predict(x_wide, bsr)
-    with pytest.raises(ValueError, match=pattern):
-        bsr_ops.bsr_predict_int8(x_wide, q)
-    with pytest.raises(ValueError, match=pattern):
-        bsr_ops.bsr_predict_gather(x_wide, bsr, sel)
-    with pytest.raises(ValueError, match=pattern):
-        bsr_ops.bsr_predict_gather_int8(x_wide, q, sel)
+    calls = [lambda m: bsr_ops.bsr_predict(x_wide, m),
+             lambda m: bsr_ops.bsr_predict_topk(x_wide, m, 3),
+             lambda m: bsr_ops.bsr_predict_gather(x_wide, m, sel),
+             lambda m: bsr_ops.bsr_predict_gather_topk(x_wide, m, sel, 3),
+             lambda m: bsr_ops.bsr_predict_gather_pq(x_wide, m, sel_pq),
+             lambda m: bsr_ops.bsr_predict_gather_pq_topk(x_wide, m,
+                                                          sel_pq, 3)]
+    for model in (bsr, bsr.quantize()):
+        for call in calls:
+            with pytest.raises(ValueError, match=pattern):
+                call(model)
 
 
 # ---------------------------------------------------------------------------

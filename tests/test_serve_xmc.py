@@ -149,6 +149,47 @@ def test_sharded_backend_masks_shard_padding():
     assert "OK" in r.stdout
 
 
+def test_packed_models_are_pytrees_sharing_one_executable_per_bucket():
+    """Both packed model classes flatten to their arrays and unflatten with
+    shape, block_shape and orig_shape intact. `_bsr_topk` takes the model
+    whole: two bsr backends over distinct models of equal geometry reuse
+    one executable per bucket, and the int8 model of the same geometry
+    compiles its own."""
+    from repro.serve.xmc import BsrBackend, Int8Backend, _bsr_topk
+
+    L, D, k = 72, 80, 3               # a geometry no other test serves
+    rng = np.random.default_rng(11)
+    m1, m2 = (to_block_sparse(jnp.asarray(
+        rng.normal(size=(L, D)).astype(np.float32)), (16, 16))
+        for _ in range(2))
+    assert m1.blocks.shape == m2.blocks.shape
+    for m, n_leaves in ((m1, 4), (m1.quantize(), 5)):
+        leaves, treedef = jax.tree_util.tree_flatten(m)
+        assert len(leaves) == n_leaves
+        assert all(isinstance(a, jax.Array) for a in leaves)
+        back = jax.tree_util.tree_unflatten(treedef, leaves)
+        assert type(back) is type(m)
+        assert (back.shape, back.block_shape, back.orig_shape) == \
+            (m.shape, m.block_shape, m.orig_shape) == \
+            ((80, 80), (16, 16), (L, D))
+        assert all(a is b for a, b in
+                   zip(jax.tree_util.tree_leaves(back), leaves))
+
+    def compiles(backend, n):
+        before = _bsr_topk._cache_size()
+        jax.block_until_ready(backend.topk(jnp.ones((n, D), jnp.float32)))
+        return _bsr_topk._cache_size() - before
+
+    b1, b2 = BsrBackend(m1, k), BsrBackend(m2, k)
+    q1 = Int8Backend(m1, k)
+    for n in (2, 4):
+        assert compiles(b1, n) == 1
+        assert compiles(b2, n) == 0
+        assert compiles(q1, n) == 1
+        assert compiles(Int8Backend(m2, k), n) == 0
+    assert b1.warmup_key()[0] == "bsr" and q1.warmup_key()[0] == "int8"
+
+
 # ---------------------------------------------------------------------------
 # Sparse checkpoint round-trip
 # ---------------------------------------------------------------------------

@@ -38,29 +38,38 @@ def _random_pruned_bsr(L, D, *, block=(16, 128), delta=0.05, seed=0,
 # Gathered-block kernel
 # ---------------------------------------------------------------------------
 
-def test_gather_kernel_matches_ref_non_tile_aligned():
+def _as(bsr, dtype):
+    return bsr.quantize() if dtype == "int8" else bsr
+
+
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+def test_gather_kernel_matches_ref_non_tile_aligned(dtype):
     """Pallas gathered-block scoring == dense-gather oracle on shapes that
     hit both row padding (L=100 -> Lp=112 with bl=16) and feature padding
-    (D=300 -> Dp=384), with an UNSORTED selection."""
+    (D=300 -> Dp=384), with an UNSORTED selection, for either dtype."""
     L, D = 100, 300
     _, bsr = _random_pruned_bsr(L, D, seed=1)
+    bsr = _as(bsr, dtype)
+    oracle = (bsr_ref.bsr_predict_gather_int8 if dtype == "int8"
+              else bsr_ref.bsr_predict_gather)
     rng = np.random.default_rng(2)
     x = jnp.asarray(rng.normal(size=(4, D)).astype(np.float32))
     sel = jnp.asarray([5, 0, 3], jnp.int32)          # arbitrary order
     got = bsr_ops.bsr_predict_gather(x, bsr, sel)
-    want = bsr_ref.bsr_predict_gather(
-        jnp.pad(x, ((0, 0), (0, bsr.shape[1] - D))), bsr, sel)
+    want = oracle(jnp.pad(x, ((0, 0), (0, bsr.shape[1] - D))), bsr, sel)
     assert got.shape == (4, 3 * 16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_gather_kernel_empty_row_block_is_exact_zero():
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+def test_gather_kernel_empty_row_block_is_exact_zero(dtype):
     """A selected row block whose labels were all Delta-pruned must come
     back EXACTLY 0.0 (the dense score of a pruned label), not garbage."""
     L, D = 64, 256
     zero_rows = list(range(16, 32))                  # kills row block 1
     _, bsr = _random_pruned_bsr(L, D, seed=3, zero_rows=zero_rows)
+    bsr = _as(bsr, dtype)
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.normal(size=(3, D)).astype(np.float32))
     out = np.asarray(bsr_ops.bsr_predict_gather(x, bsr, jnp.asarray([1, 2])))
@@ -68,11 +77,13 @@ def test_gather_kernel_empty_row_block_is_exact_zero():
     assert (out[:, 16:] != 0.0).any()                # block 2: real scores
 
 
-def test_gather_topk_full_coverage_is_bit_exact():
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+def test_gather_topk_full_coverage_is_bit_exact(dtype):
     """sel = every row block (sorted) reproduces the exhaustive fused
-    predict->topk bit-for-bit, tie order included."""
+    predict->topk bit-for-bit, tie order included, in either dtype."""
     L, D, k = 100, 300, 5
     _, bsr = _random_pruned_bsr(L, D, seed=5)
+    bsr = _as(bsr, dtype)
     R = bsr.shape[0] // bsr.block_shape[0]
     rng = np.random.default_rng(6)
     x = jnp.asarray(rng.normal(size=(8, D)).astype(np.float32))
