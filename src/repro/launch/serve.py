@@ -237,6 +237,7 @@ def serve_xmc_server(args) -> None:
                   f"batches={st['batches']} (full={st['launched_full']} "
                   f"deadline={st['launched_deadline']} "
                   f"forced={st['launched_forced']}) "
+                  f"slot_waits={st['slot_waits']} "
                   f"rows={st['rows_launched']} "
                   f"padded={st['rows_padded']} "
                   f"weight_passes={st['weight_passes']} "

@@ -9,11 +9,15 @@ wraps an engine in an arrival-time-aware serving loop:
     largest bucket fills, OR when the oldest queued request has waited
     `max_batch_delay_ms` (continuous batching). Low traffic never waits for
     a bucket to fill; high traffic always ships full buckets.
-  * **Double-buffered dispatch** — the dispatcher thread packs/pads the
-    next batch and hands the (asynchronously dispatched) device computation
-    to a completion thread over a bounded hand-off queue, so host-side
-    batching of batch b+1 overlaps with batch b's device compute. The
-    bounded depth (`max_inflight`) is the dispatch-side backpressure.
+  * **Double-buffered dispatch** — `max_inflight` bounds the launches
+    outstanding on the device (dispatched, not yet finished, counting the
+    one the completion thread waits on). The dispatcher takes a device slot
+    before it forms a batch, so a batch formed while the device is full
+    takes every row that arrived meanwhile instead of queueing behind
+    other launches; a slot comes back as soon as its launch finishes on
+    the device. At the default 2 one launch runs while the next waits
+    behind it, so the device never waits on the host, and the completion
+    thread fetches and resolves batch b while b+1 computes.
   * **Admission control** — past `max_queue` pending requests, `submit`
     resolves the future immediately with a `Rejected` result instead of
     growing the queue without bound: under overload, queue wait stays
@@ -43,11 +47,12 @@ Tracing: the worker threads open `jax.profiler.TraceAnnotation` spans
 around each step of a micro-batch, so a profiler trace puts the host's
 work on the device's clock (free when no profiler runs). Dispatcher:
 `xmc.dispatch.idle` (queue empty), `xmc.dispatch.hold` (requests queued,
-neither fill nor deadline reached), then per batch `xmc.dispatch.form`,
-`.put`, `.launch` and `.handoff` (blocked while `max_inflight` batches are
-outstanding). Completion thread, per batch: `xmc.complete.device`,
-`.fetch`, `.resolve`. `stats()` counts launches by reason, the rows
-launched and padded, and the passes the launches made over the weights.
+neither fill nor deadline reached), `xmc.dispatch.slot` (a batch is
+launchable and all `max_inflight` launches are on the device), then per
+batch `xmc.dispatch.form`, `.put` and `.launch`. Completion thread, per
+batch: `xmc.complete.device`, `.fetch`, `.resolve`. `stats()` counts
+launches by reason, the launches that waited for a slot, the rows launched
+and padded, and the passes the launches made over the weights.
 
 The batching policy itself lives in `serve.batching.MicroBatchQueue`
 (`next_batch`); the engine's synchronous `step()` path is untouched and
@@ -168,8 +173,10 @@ class XMCServer:
     max_queue : admission bound on requests waiting for launch (dispatched/
         in-flight work does not count). None = unbounded (closed-loop /
         trusted callers only).
-    max_inflight : depth of the dispatch->completion hand-off; 2 =
-        double-buffering (pack batch b+1 while batch b computes).
+    max_inflight : launches outstanding on the device, dispatched and not
+        yet finished; a launchable batch is formed only once one of them
+        finishes. 2 = double-buffering (batch b+1 queued on the device
+        while batch b computes).
     start : spawn the worker threads now. Pass False to pre-load requests
         and start later — with everything queued up front the launch
         grouping is identical to `engine.step()`'s drain, which is how the
@@ -198,7 +205,7 @@ class XMCServer:
         self.latency = LatencyStats()        # arrival -> completion
         self.queue_wait = LatencyStats()     # arrival -> device dispatch
         self.counters = {"accepted": 0, "rejected": 0, "completed": 0,
-                         "batches": 0, "swaps": 0,
+                         "batches": 0, "slot_waits": 0, "swaps": 0,
                          **{f"launched_{r}": 0 for r in LAUNCH_REASONS},
                          "rows_launched": 0, "rows_padded": 0,
                          "weight_passes": 0, "topk_grouped": 0}
@@ -206,7 +213,8 @@ class XMCServer:
         self.last_swap: Optional[dict] = None   # timing of the latest swap
         self._cv = threading.Condition()
         self._by_rid: dict[int, _Assembly] = {}
-        self._inflight: queue_mod.Queue = queue_mod.Queue(maxsize=max_inflight)
+        self._slots = threading.Semaphore(max_inflight)   # device slots
+        self._inflight: queue_mod.Queue = queue_mod.Queue()  # FIFO hand-off
         self._stopping = False
         self._started = False
         self._dispatcher = threading.Thread(
@@ -251,9 +259,9 @@ class XMCServer:
 
     def _drain_unstarted(self) -> None:
         """A never-started server still owes answers on stop: run the loop
-        body inline, completing after every dispatch so the bounded
-        hand-off queue never fills without a completion thread to drain it
-        (tests build servers with start=False)."""
+        body inline, completing after every dispatch so its device slot is
+        back before the next one is taken (tests build servers with
+        start=False)."""
         while self._dispatch_once(force=True):
             self._complete_pending()
         self._complete_pending()
@@ -352,25 +360,40 @@ class XMCServer:
     # -- worker loops -------------------------------------------------------
 
     def _dispatch_once(self, *, force: bool = False) -> bool:
-        """Form one micro-batch if launchable, dispatch it to the device,
-        and hand it to the completion side. Returns False when nothing was
-        launchable."""
+        """Take a device slot, then form one micro-batch if launchable,
+        dispatch it to the device, and hand it to the completion side.
+        Returns False when nothing was launchable."""
         delay_s = self.max_batch_delay_ms / 1e3
         with self._cv:
             if not self.queue:
                 return False
-            with TraceAnnotation("xmc.dispatch.form"):
-                mb = self.queue.next_batch(max_delay_s=delay_s, force=force)
-            engine = self.engine     # captured with the batch: a concurrent
-            # swap() must not tear one micro-batch across two models
-        if mb is None:
-            return False
-        engine.ensure_warm(mb.bucket)
-        with TraceAnnotation("xmc.dispatch.put"):
-            xb = jnp.asarray(mb.x)               # host pack -> device put
-        t_dispatch = time.monotonic()
-        with TraceAnnotation("xmc.dispatch.launch"):
-            scores, labels = engine.backend.topk(xb)    # async dispatch
+        # Wait outside the lock: submit() keeps queueing rows meanwhile,
+        # and the batch formed below takes them all.
+        if not self._slots.acquire(blocking=False):
+            self.counters["slot_waits"] += 1
+            with TraceAnnotation("xmc.dispatch.slot"):
+                self._slots.acquire()
+        launched = False
+        try:
+            with self._cv:
+                with TraceAnnotation("xmc.dispatch.form"):
+                    mb = self.queue.next_batch(max_delay_s=delay_s,
+                                               force=force)
+                engine = self.engine     # captured with the batch: a
+                # concurrent swap() must not tear one micro-batch across
+                # two models
+            if mb is None:
+                return False
+            engine.ensure_warm(mb.bucket)
+            with TraceAnnotation("xmc.dispatch.put"):
+                xb = jnp.asarray(mb.x)               # host pack -> device put
+            t_dispatch = time.monotonic()
+            with TraceAnnotation("xmc.dispatch.launch"):
+                scores, labels = engine.backend.topk(xb)    # async dispatch
+            launched = True
+        finally:
+            if not launched:
+                self._slots.release()
         rows = sum(mb.row_counts)
         c = self.counters
         c["batches"] += 1
@@ -383,8 +406,7 @@ class XMCServer:
         grouped = getattr(engine.backend, "topk_grouped", None)
         if grouped is not None:
             c["topk_grouped"] += grouped(mb.bucket)
-        with TraceAnnotation("xmc.dispatch.handoff"):
-            self._inflight.put((mb, scores, labels, t_dispatch))
+        self._inflight.put((mb, scores, labels, t_dispatch))
         return True
 
     def _dispatch_loop(self) -> None:
@@ -416,8 +438,11 @@ class XMCServer:
         self._inflight.put(_STOP)
 
     def _complete_batch(self, mb, scores, labels, t_dispatch: float) -> None:
-        with TraceAnnotation("xmc.complete.device"):
-            jax.block_until_ready(labels)
+        try:
+            with TraceAnnotation("xmc.complete.device"):
+                jax.block_until_ready(labels)
+        finally:
+            self._slots.release()      # the device is done with this launch
         with TraceAnnotation("xmc.complete.fetch"):
             scores, labels = np.asarray(scores), np.asarray(labels)
         t_done = time.monotonic()
@@ -474,11 +499,12 @@ class XMCServer:
         admission control bounds), each over the most recent
         `LATENCY_SAMPLES` requests. `launched_full` / `_deadline` /
         `_forced` count batches by why they launched (they sum to
-        `batches`); `rows_launched` counts the requests' rows and
-        `rows_padded` the bucket rows added to them. `weight_passes` sums
-        the passes each launch made over the weights, as a backend with a
-        `weight_passes(bucket)` reports them (the BSR backend does); other
-        backends add nothing. `topk_grouped` counts the launches whose
+        `batches`); `slot_waits` counts the batches that were launchable
+        but found all `max_inflight` device slots taken; `rows_launched`
+        counts the requests' rows and `rows_padded` the bucket rows added
+        to them. `weight_passes` sums the passes each launch made over the
+        weights, as a backend with a `weight_passes(bucket)` reports them
+        (the BSR backend does); other backends add nothing. `topk_grouped` counts the launches whose
         top-k took the grouped form, as a backend with a
         `topk_grouped(bucket)` reports it (the BSR backend does)."""
         out = dict(self.counters)

@@ -1,7 +1,10 @@
 """Continuous-batching async server: deadline launch, admission control,
 multi-model routing, and sync-vs-async bit-identity per backend."""
 
+import os
+import sys
 import tempfile
+import threading
 import time
 
 import numpy as np
@@ -109,6 +112,282 @@ def test_stats_count_launch_reasons_and_padded_rows():
     st = server.stats()
     assert (st["batches"], st["launched_deadline"]) == (1, 1)
     assert (st["rows_launched"], st["rows_padded"]) == (1, 1)
+
+
+# ---------------------------------------------------------------------------
+# Device slots: max_inflight bounds the launches outstanding on the device
+# ---------------------------------------------------------------------------
+
+class _Launch:
+    """One launch on `_HeldBackend`'s device: unfinished until released."""
+
+    def __init__(self, backend, x):
+        self.backend, self.x = backend, x
+        self.done = threading.Event()
+        self.waited = False
+
+    def wait(self):
+        self.done.wait()
+        with self.backend.cv:
+            if not self.waited:
+                self.waited = True
+                self.backend.outstanding -= 1
+                self.backend.cv.notify_all()
+
+
+class _Held:
+    """A result leaf of a `_Launch`: `jax.block_until_ready` waits for the
+    launch, `np.asarray` reads its value."""
+
+    def __init__(self, value, launch):
+        self.value, self.launch = value, launch
+
+    def block_until_ready(self):
+        self.launch.wait()
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.value, dtype)
+
+
+class _HeldBackend:
+    """A fake device whose launches stay unfinished until the test releases
+    them (`hold`), as an asynchronous dispatch does while the chip is busy.
+    A launch is outstanding from `topk` until a wait on it returns; `peak`
+    is the most outstanding at once. Scores carry each row's first k
+    features, so a test can tell which rows a launch took."""
+
+    name = "held"
+
+    def __init__(self, k=3, n_labels=96):
+        self.k, self.n_labels = k, n_labels
+        self.cv = threading.Condition()
+        self.hold = False
+        self.fail = False
+        self.launches: list[_Launch] = []
+        self.outstanding = self.peak = 0
+
+    def topk(self, x):
+        if self.fail:
+            self.fail = False
+            raise RuntimeError("launch failed")
+        launch = _Launch(self, np.asarray(x))
+        if not self.hold:
+            launch.done.set()
+        with self.cv:
+            self.launches.append(launch)
+            self.outstanding += 1
+            self.peak = max(self.peak, self.outstanding)
+            self.cv.notify_all()
+        n = launch.x.shape[0]
+        labels = np.tile(np.arange(self.k, dtype=np.int32), (n, 1))
+        return (_Held(launch.x[:, :self.k], launch),
+                _Held(labels, launch))
+
+    def release(self, i):
+        self.launches[i].done.set()
+
+    def release_all(self):
+        self.hold = False
+        with self.cv:
+            for launch in self.launches:
+                launch.done.set()
+
+
+def _held_server(max_inflight, *, buckets=(1, 2, 4, 8), delay_ms=0.0,
+                 start=True):
+    """A server over a `_HeldBackend`, warmed with its launches ready and
+    then set to hold every launch."""
+    be = _HeldBackend()
+    engine = XMCEngine(be, buckets=buckets, warmup=True, n_features=128)
+    be.launches.clear()
+    be.outstanding = be.peak = 0
+    be.hold = True
+    server = XMCServer(engine, max_batch_delay_ms=delay_ms,
+                       max_inflight=max_inflight, start=start)
+    return server, be
+
+
+def _until(pred, timeout=30.0):
+    t_end = time.monotonic() + timeout
+    while not pred():
+        assert time.monotonic() < t_end, "condition not reached in time"
+        time.sleep(0.001)
+
+
+def _row(i):
+    return np.full((1, 128), float(i), np.float32)
+
+
+@pytest.mark.parametrize("max_inflight", [1, 2, 3])
+def test_launches_outstanding_never_exceed_max_inflight(max_inflight):
+    """`max_inflight` launches fill the device, the one the completion
+    thread waits on among them; the next launchable batch waits for one to
+    finish and is not launched behind them."""
+    server, be = _held_server(max_inflight)
+    futures = []
+    for i in range(max_inflight):
+        futures.append(server.submit(_row(i)))
+        _until(lambda: len(be.launches) == i + 1)
+    futures.append(server.submit(_row(max_inflight)))
+    _until(lambda: server.stats()["slot_waits"] == 1)
+    time.sleep(0.05)
+    assert len(be.launches) == max_inflight
+    be.release(0)
+    _until(lambda: len(be.launches) == max_inflight + 1)
+    done = threading.Event()
+
+    def finish_in_order():          # the device ends one launch at a time
+        i = 1
+        while not done.is_set():
+            if i < len(be.launches):
+                be.release(i)
+                i += 1
+            time.sleep(0.002)
+
+    device = threading.Thread(target=finish_in_order)
+    device.start()
+    for i in range(max_inflight + 1, max_inflight + 40):
+        futures.append(server.submit(_row(i)))
+        time.sleep(0.001)
+    for f in futures:
+        assert isinstance(f.result(timeout=30), XMCResult)
+    done.set()
+    device.join()
+    server.stop()
+    assert be.peak == max_inflight
+
+
+def test_rows_queued_while_the_device_is_full_go_out_together():
+    """The batch is formed once a slot frees, so it takes every row that
+    arrived while the device was full, in arrival order."""
+    server, be = _held_server(1)
+    first = server.submit(_row(0))
+    _until(lambda: len(be.launches) == 1)
+    rest = [server.submit(_row(i)) for i in range(1, 5)]
+    _until(lambda: server.stats()["slot_waits"] == 1)
+    be.release(0)
+    _until(lambda: len(be.launches) == 2)
+    np.testing.assert_array_equal(be.launches[1].x[:, 0], [1, 2, 3, 4])
+    be.release_all()
+    for i, f in enumerate([first, *rest]):
+        np.testing.assert_array_equal(f.result(timeout=30).scores, [[i] * 3])
+    server.stop()
+    st = server.stats()
+    assert (st["batches"], st["rows_launched"], st["slot_waits"]) == (2, 5, 1)
+
+
+def test_slot_waits_count_only_launchable_batches_that_found_no_slot():
+    """Rows held by the batching rule wait for their fill or deadline, not
+    for a slot, and count nothing; a launchable batch that finds the device
+    free counts nothing either."""
+    server, be = _held_server(1, buckets=(2,), delay_ms=120_000.0)
+    futures = [server.submit(_row(0))]              # held: bucket not full
+    time.sleep(0.05)
+    assert (len(be.launches), server.stats()["slot_waits"]) == (0, 0)
+    futures.append(server.submit(_row(1)))          # full: launches
+    _until(lambda: len(be.launches) == 1)
+    futures.append(server.submit(_row(2)))          # held, device full
+    time.sleep(0.05)
+    assert server.stats()["slot_waits"] == 0
+    futures.append(server.submit(_row(3)))          # full, device full
+    _until(lambda: server.stats()["slot_waits"] == 1)
+    be.release_all()
+    futures.append(server.submit(_row(4)))          # held until stop
+    _until(lambda: futures[3].done())
+    server.stop()                                   # forced, slot free
+    for f in futures:
+        assert isinstance(f.result(timeout=0), XMCResult)
+    st = server.stats()
+    assert (st["batches"], st["launched_full"], st["launched_forced"],
+            st["slot_waits"]) == (3, 2, 1, 1)
+
+
+def test_a_launch_that_raises_gives_its_slot_back():
+    server, be = _held_server(1, start=False)
+    be.hold = False
+    server.submit(_row(0))
+    be.fail = True
+    with pytest.raises(RuntimeError, match="launch failed"):
+        server._dispatch_once(force=True)
+    fut = server.submit(_row(1))
+    # stop() drains inline and needs the one slot.
+    stopper = threading.Thread(target=server.stop, daemon=True)
+    stopper.start()
+    stopper.join(timeout=30)
+    assert not stopper.is_alive(), "the failed launch kept its slot"
+    assert isinstance(fut.result(timeout=0), XMCResult)
+    assert server.stats()["slot_waits"] == 0
+
+
+def test_slots_hold_under_many_submitters():
+    """Stress: more submitting threads than cores, a thread switch every
+    microsecond, and a device that ends each launch in order at once: no
+    more than `max_inflight` launches are ever outstanding, and every
+    request resolves to its own row."""
+    server, be = _held_server(2, buckets=(1, 2, 4, 8, 16))
+    done = threading.Event()
+
+    def finish_in_order():
+        i = 0
+        while not done.is_set() or i < len(be.launches):
+            if i < len(be.launches):
+                be.release(i)
+                i += 1
+            else:
+                time.sleep(0.0002)
+
+    n_threads, per_thread = 2 * (os.cpu_count() or 1) + 4, 25
+    futures = [[] for _ in range(n_threads)]
+
+    def submit_rows(t):
+        for j in range(per_thread):
+            futures[t].append(server.submit(_row(t * per_thread + j)))
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        device = threading.Thread(target=finish_in_order)
+        device.start()
+        submitters = [threading.Thread(target=submit_rows, args=(t,))
+                      for t in range(n_threads)]
+        for th in submitters:
+            th.start()
+        for th in submitters:
+            th.join(timeout=60)
+            assert not th.is_alive()
+        for t, fs in enumerate(futures):
+            for j, f in enumerate(fs):
+                np.testing.assert_array_equal(
+                    f.result(timeout=60).scores, [[t * per_thread + j] * 3])
+        server.stop()
+    finally:
+        sys.setswitchinterval(switch)
+        done.set()
+        device.join(timeout=60)
+    assert not device.is_alive()
+    assert be.peak <= 2
+    assert server.stats()["completed"] == n_threads * per_thread
+
+
+@pytest.mark.parametrize("started", [True, False])
+def test_stop_resolves_every_request_through_the_slots(started):
+    """stop() flushes the queue through the slots: with the server started,
+    batches launched behind held ones finish once the device does; never
+    started, the inline drain completes each launch before the next."""
+    server, be = _held_server(1, buckets=(1, 2), start=started)
+    if not started:
+        be.hold = False
+    futures = [server.submit(_row(i)) for i in range(7)]
+    if started:
+        _until(lambda: len(be.launches) == 1)
+        releaser = threading.Thread(target=be.release_all)
+        threading.Timer(0.05, releaser.start).start()
+    server.stop()
+    for i, f in enumerate(futures):
+        np.testing.assert_array_equal(f.result(timeout=0).scores, [[i] * 3])
+    assert server.stats()["completed"] == 7
+    assert be.peak == 1
 
 
 # ---------------------------------------------------------------------------
